@@ -17,6 +17,7 @@ import json
 import time
 from typing import Dict, List
 
+from repro import compile_cache
 from repro.core import (FeedConfig, FeedManager, RefStore,
                         SyntheticAdapter, pipeline)
 from repro.core.enrich import queries as Q
@@ -58,6 +59,9 @@ def write_csv(path: str) -> None:
 
 
 def make_manager(scale: float = 0.02, overrides=None) -> FeedManager:
+    """Reference tables + manager; every driver calls this before its
+    first compile, so it also places JAX's persistent compile cache."""
+    compile_cache.enable()
     store = RefStore()
     Q.make_reference_tables(store, scale=scale, seed=7,
                             scale_overrides=overrides)

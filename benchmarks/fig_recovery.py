@@ -9,8 +9,8 @@ Sections:
                 feed (WAL + coordinated checkpoints) against a throttled
                 synthetic stream while a rolling updater upserts
                 safety_levels keys; the parent SIGKILLs it at a random
-                point of the ingest window, then recovers the feed
-                in-process (``FeedManager.resume``) from the surviving
+                point of the ingest window, then a second child recovers
+                the feed (``FeedManager.resume``) from the surviving
                 durable directory.  Hard asserts per round: rows lost
                 = 0 and rows duplicated = 0 over the full stream.
                 Emits the replay backlog and the recovery time (resume
@@ -26,12 +26,16 @@ Sections:
 
 The child re-enters this module with ``--child``; the crash is a real
 SIGKILL of a separate interpreter, so no Python-level cleanup (atexit,
-finally, flush-on-close) can soften it.
+finally, flush-on-close) can soften it.  The resume-and-verify step runs
+in its own child too (``--resume``): the parent never starts JAX's
+backend while a child lives, because an accelerator belongs to one
+process at a time.
 """
 
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import shutil
 import subprocess
@@ -119,33 +123,15 @@ def child_main(args) -> None:
 # parent: kill, restart, verify exactly-once
 # ---------------------------------------------------------------------------
 
-def run_round(rnd: int, dur_dir: str, total: int, batch: int, rate: float,
-              update_every: float, update_keys: int, rng) -> dict:
-    name = f"rec{rnd}"
-    seed = 100 + rnd
-    cmd = [sys.executable, "-m", "benchmarks.fig_recovery", "--child",
-           "--durable-dir", dur_dir, "--total", str(total),
-           "--batch", str(batch), "--seed", str(seed),
-           "--rate", str(rate), "--name", name,
-           "--update-every", str(update_every),
-           "--update-keys", str(update_keys), "--json-out", ""]
-    proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True)
-    try:
-        for line in proc.stdout:
-            if line.startswith("READY"):
-                break
-        else:
-            raise RuntimeError(f"round {rnd}: child died before READY "
-                               f"(rc={proc.wait()})")
-        # kill at a random point of the nominal ingest window
-        window = total / rate
-        delay = rng.uniform(0.1 * window, 0.8 * window)
-        time.sleep(delay)
-    finally:
-        proc.kill()
-        proc.wait()
-        proc.stdout.close()
+def resume_main(args) -> None:
+    """Second child: resume the killed feed, verify, report one line."""
+    r = resume_and_verify(args.durable_dir, args.total, args.batch,
+                          args.seed, args.name, args.kill_after)
+    print("RESULT " + json.dumps(r), flush=True)
 
+
+def resume_and_verify(dur_dir: str, total: int, batch: int, seed: int,
+                      name: str, delay: float) -> dict:
     # restart: fresh manager, fresh (pristine) ref tables, same plan
     mgr = make_manager(scale=0.02)
     plan = durable_plan(dur_dir, total, batch, seed, None, name)
@@ -163,12 +149,54 @@ def run_round(rnd: int, dur_dir: str, total: int, batch: int, rate: float,
     counts = stored_id_counts(h.storage)
     lost = total - len(counts)
     dups = sum(c - 1 for c in counts.values())
-    assert lost == 0, (f"round {rnd}: {lost} rows lost after kill at "
+    assert lost == 0, (f"{name}: {lost} rows lost after kill at "
                        f"+{delay:.2f}s (backlog={backlog})")
-    assert dups == 0, (f"round {rnd}: {dups} duplicate rows after kill "
+    assert dups == 0, (f"{name}: {dups} duplicate rows after kill "
                        f"at +{delay:.2f}s (backlog={backlog})")
     return {"kill_after_s": delay, "backlog": backlog,
             "recovery_s": recovery_s, "lost": lost, "dups": dups}
+
+
+def run_round(rnd: int, dur_dir: str, total: int, batch: int, rate: float,
+              update_every: float, update_keys: int, rng) -> dict:
+    name = f"rec{rnd}"
+    seed = 100 + rnd
+    common = ["--durable-dir", dur_dir, "--total", str(total),
+              "--batch", str(batch), "--seed", str(seed), "--name", name,
+              "--json-out", ""]
+    cmd = [sys.executable, "-m", "benchmarks.fig_recovery", "--child",
+           *common, "--rate", str(rate),
+           "--update-every", str(update_every),
+           "--update-keys", str(update_keys)]
+    proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, text=True)
+    try:
+        for line in proc.stdout:
+            if line.startswith("READY"):
+                break
+        else:
+            raise RuntimeError(f"round {rnd}: child died before READY "
+                               f"(rc={proc.wait()})")
+        # kill at a random point of the nominal ingest window
+        window = total / rate
+        delay = rng.uniform(0.1 * window, 0.8 * window)
+        time.sleep(delay)
+    finally:
+        proc.kill()
+        proc.wait()
+        proc.stdout.close()
+
+    # the killed child has exited, so the resuming child is the only
+    # process that holds JAX's backend
+    done = subprocess.run(
+        [sys.executable, "-m", "benchmarks.fig_recovery", "--resume",
+         *common, "--kill-after", repr(delay)],
+        stdout=subprocess.PIPE, text=True)
+    result = [ln for ln in done.stdout.splitlines()
+              if ln.startswith("RESULT ")]
+    if done.returncode != 0 or not result:
+        raise RuntimeError(f"round {rnd}: resume/verify failed "
+                           f"(rc={done.returncode}); see its stderr")
+    return json.loads(result[-1][len("RESULT "):])
 
 
 def bench_kill_restart(base_dir: str, kills: int, total: int, batch: int,
@@ -266,6 +294,10 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--child", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--resume", action="store_true",
+                    help=argparse.SUPPRESS)
+    ap.add_argument("--kill-after", type=float, default=0.0,
+                    help=argparse.SUPPRESS)
     ap.add_argument("--durable-dir", default="",
                     help=argparse.SUPPRESS)
     ap.add_argument("--seed", type=int, default=0,
@@ -290,6 +322,8 @@ if __name__ == "__main__":
     args = ap.parse_args()
     if args.child:
         child_main(args)
+    elif args.resume:
+        resume_main(args)
     else:
         import tempfile
         base = args.work_dir or tempfile.mkdtemp(prefix="fig_recovery_")

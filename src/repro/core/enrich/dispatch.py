@@ -68,8 +68,8 @@ class DispatchConfig:
     min_pallas_rows: int = 1024   # "auto": below this the jnp path wins
     bucket_min: int = 512         # smallest probe bucket
     bucket_max: int = 1 << 22     # cap: beyond this, chunk upstream
-    # segment_topk kernel envelope: its (k_pad, S_pad) winner tables and
-    # the (block, S_pad) one-hot tile live in VMEM, and its work is
+    # segment_topk kernel envelope: its (S_pad, k) winner tables and
+    # the (S_pad, block) one-hot tile live in VMEM, and its work is
     # O(k*R) vs the reference sort's O(R log R) — route to the kernel
     # only inside these bounds (Q3's 50K-country top-3 stays on the
     # reference sort; query-layer group-bys land inside)

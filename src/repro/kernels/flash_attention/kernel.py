@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams after 0.4.x
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))
-
 NEG_INF = -1e30
 
 
@@ -138,7 +134,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d_pad), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

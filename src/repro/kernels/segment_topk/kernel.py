@@ -9,15 +9,18 @@ in-register one-hot tile the segment-sum kernel uses (DESIGN.md §2):
               r not selected in rounds < j}   (ties -> lowest row)
 
 The grid is (k, R/block): the slow dimension is the round, the fast one
-streams value/segment blocks through VMEM.  The (k_pad, S_pad) winner
-tables (value + row index) live in the revisited output block; a round
-reads the previous rounds' winner rows to mask them out — the per-row
-"am I already taken" test reuses the one-hot tile as a gather
-(``where(onehot, taken_row, -1)`` + a lane max), so nothing irregular
-ever touches memory.  k rounds re-stream R rows: O(kR) work against the
-reference's O(R log R) composite sort, but each pass is pure VPU
-compare/max on data already in VMEM, and the k the workload cares about
-(paper Q3: top-3; query topk: single digits) is tiny.
+streams lane-dense (1, block) value/segment rows through VMEM.  The
+one-hot tile is (S_pad, block): segments on sublanes, rows on lanes.  The
+(S_pad, k) winner tables (value + row index) live in the revisited output
+block, round j in column j; a round reads the previous rounds' winner
+columns to mask them out — the per-row "am I already taken" test reuses
+the one-hot tile as a gather (``where(onehot, taken_col, -1)`` + a
+sublane max), so nothing irregular ever touches memory.  Columns are read
+and written through a lane mask, never a dynamic lane index.  k rounds
+re-stream R rows: O(kR) work against the reference's O(R log R)
+composite sort, but each pass is pure VPU compare/max on data already in
+VMEM, and the k the workload cares about (paper Q3: top-3; query topk:
+single digits) is tiny.
 
 Selection order is deterministic and bit-identical to the reference
 oracle's composite-key sort: values descend, ties break toward the lower
@@ -37,49 +40,54 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_BIG = 2**31 - 1  # python int: pallas kernels cannot capture array constants
 
-def _kernel(values_ref, seg_ref, idx_ref, val_ref, *, k: int,
-            block_r: int):
+
+def _kernel(values_ref, seg_ref, idx_ref, val_ref, *, block_r: int):
     j = pl.program_id(0)          # selection round (slow)
-    # x64 mode: every dynamic-slice start must share one index dtype
-    jd = j.astype(jnp.int64)
     i = pl.program_id(1)          # row block (fast)
-    s_pad = idx_ref.shape[1]
+    s_pad, k = idx_ref.shape
+    slot = jax.lax.broadcasted_iota(jnp.int32, (s_pad, k), 1)
 
-    @pl.when(i == 0)
-    def _init():                  # open round j with an empty winner row
-        neg = jnp.full((1, s_pad), -1, jnp.int32)
-        pl.store(idx_ref, (pl.ds(jd, 1), slice(None)), neg)
-        pl.store(val_ref, (pl.ds(jd, 1), slice(None)), neg)
+    @pl.when((j == 0) & (i == 0))
+    def _init():                  # every round opens with no winners
+        idx_ref[...] = jnp.full_like(idx_ref, -1)
+        val_ref[...] = jnp.full_like(val_ref, -1)
 
-    vals = values_ref[...]                       # (block_r,) int32, >= 0
-    seg = seg_ref[...]                           # (block_r,) int32
-    onehot = (seg[:, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (block_r, s_pad), 1))
-    row_mat = (jax.lax.broadcasted_iota(jnp.int32, (block_r, s_pad), 0)
-               + i * block_r)
-    rows = row_mat[:, 0]
+    def column(ref, jj):          # (s_pad, 1): round jj's winners (>= -1)
+        return jnp.max(jnp.where(slot == jj, ref[...], -1), axis=1,
+                       keepdims=True)
+
+    seg = seg_ref[...]                           # (1, block_r) int32
+    onehot = jax.lax.broadcasted_iota(jnp.int32, (s_pad, block_r), 0) == seg
+    rows = (jax.lax.broadcasted_iota(jnp.int32, (1, block_r), 1)
+            + i * block_r)
+
     # mask rows already selected by earlier rounds: the winner row of
     # round jj for THIS row's segment, fetched through the one-hot tile
-    taken = jnp.zeros((block_r,), jnp.bool_)
-    for jj in range(k):
-        prev = pl.load(idx_ref, (pl.ds(jj, 1), slice(None)))   # (1, s_pad)
-        mine = jnp.max(jnp.where(onehot, prev, -1), axis=1)    # (block_r,)
-        taken |= (mine == rows) & (jj < j)
-    cand = jnp.where(taken, -1, vals)
+    def mask_round(jj, taken):
+        prev = column(idx_ref, jj)
+        mine = jnp.max(jnp.where(onehot, prev, -1), axis=0, keepdims=True)
+        return jnp.where(mine == rows, 1, taken)
+
+    taken = jax.lax.fori_loop(0, j, mask_round,
+                              jnp.zeros((1, block_r), jnp.int32))
+    cand = jnp.where(taken != 0, -1, values_ref[...])    # (1, block_r)
     # per-segment argmax within the block (first max -> lowest row)
-    tile = jnp.where(onehot, cand[:, None], -1)  # (block_r, s_pad)
-    bmax = jnp.max(tile, axis=0)
-    brow = jnp.argmax(tile, axis=0).astype(jnp.int32) + i * block_r
-    accv = pl.load(val_ref, (pl.ds(jd, 1), slice(None)))[0]
-    acci = pl.load(idx_ref, (pl.ds(jd, 1), slice(None)))[0]
+    tile = jnp.where(onehot, cand, -1)                   # (s_pad, block_r)
+    bmax = jnp.max(tile, axis=1, keepdims=True)          # (s_pad, 1)
+    brow = jnp.min(jnp.where(tile == bmax, rows, _BIG), axis=1,
+                   keepdims=True)
+    accv = column(val_ref, j)
+    acci = column(idx_ref, j)
     # blocks revisit in ascending row order, so strict > keeps the
     # earliest row on value ties — stable-sort order, like the oracle
     better = bmax > accv
-    pl.store(val_ref, (pl.ds(jd, 1), slice(None)),
-             jnp.where(better, bmax, accv)[None, :])
-    pl.store(idx_ref, (pl.ds(jd, 1), slice(None)),
-             jnp.where(better, brow, acci)[None, :])
+    here = slot == j
+    val_ref[...] = jnp.where(here, jnp.where(better, bmax, accv),
+                             val_ref[...])
+    idx_ref[...] = jnp.where(here, jnp.where(better, brow, acci),
+                             idx_ref[...])
 
 
 def _round_up(x: int, m: int) -> int:
@@ -95,31 +103,33 @@ def segment_topk_pallas(values: jax.Array, seg: jax.Array,
     winner sentinel is -1); seg: (R,) int32, rows with seg outside
     [0, num_segments) are dropped (the shared padding convention).
     Returns (num_segments, k) int32 row indices, -1 where the segment has
-    fewer than k rows."""
+    fewer than k rows.  ``block_r`` is a multiple of 128 (lanes)."""
     r = values.shape[0]
     r_pad = _round_up(max(r, block_r), block_r)
-    s_pad = _round_up(max(num_segments, 1), 128)
-    k_pad = _round_up(k, 8)
+    s_pad = _round_up(max(num_segments, 1), 8)
     values = jnp.pad(jnp.maximum(values.astype(jnp.int32), 0),
-                     (0, r_pad - r))
+                     (0, r_pad - r)).reshape(1, r_pad)
     seg = jnp.pad(seg.astype(jnp.int32), (0, r_pad - r),
-                  constant_values=s_pad)
+                  constant_values=s_pad).reshape(1, r_pad)
 
-    idx, _ = pl.pallas_call(
-        functools.partial(_kernel, k=k, block_r=block_r),
-        grid=(k, r_pad // block_r),
-        in_specs=[
-            pl.BlockSpec((block_r,), lambda j, i: (i,)),
-            pl.BlockSpec((block_r,), lambda j, i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((k_pad, s_pad), lambda j, i: (0, 0)),
-            pl.BlockSpec((k_pad, s_pad), lambda j, i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((k_pad, s_pad), jnp.int32),   # winner rows
-            jax.ShapeDtypeStruct((k_pad, s_pad), jnp.int32),   # winner vals
-        ],
-        interpret=interpret,
-    )(values, seg)
-    return idx[:k, :num_segments].T
+    # 32-bit kernel: trace it with the package's x64 mode off (Mosaic
+    # cannot lower 64-bit literals and index types)
+    with jax.enable_x64(False):
+        idx, _ = pl.pallas_call(
+            functools.partial(_kernel, block_r=block_r),
+            grid=(k, r_pad // block_r),
+            in_specs=[
+                pl.BlockSpec((1, block_r), lambda j, i: (0, i)),
+                pl.BlockSpec((1, block_r), lambda j, i: (0, i)),
+            ],
+            out_specs=[
+                pl.BlockSpec((s_pad, k), lambda j, i: (0, 0)),
+                pl.BlockSpec((s_pad, k), lambda j, i: (0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((s_pad, k), jnp.int32),   # winner rows
+                jax.ShapeDtypeStruct((s_pad, k), jnp.int32),   # winner vals
+            ],
+            interpret=interpret,
+        )(values, seg)
+    return idx[:num_segments]

@@ -64,13 +64,9 @@ _WIRE_FACTOR = {
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: 0.4.x
-    returns a one-element list of per-program dicts, >= 0.5 returns the dict
-    itself.  Always returns a dict (empty when XLA reports nothing)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``Compiled.cost_analysis()`` as a dict (empty when XLA reports
+    nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _shape_bytes(text: str) -> int:

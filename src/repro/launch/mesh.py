@@ -16,18 +16,14 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and the
-    ``jax.sharding.AxisType`` enum) only exist in jax >= 0.5; 0.4.x builds
-    the same Auto-typed mesh without the kwarg."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto-typed (sharding propagated
+    by the compiler, as the model code assumes)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 @dataclasses.dataclass(frozen=True)

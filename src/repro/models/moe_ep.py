@@ -24,12 +24,10 @@ routing math (top-k, normalized weights, load-balance aux) is shared.
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -37,12 +35,6 @@ from repro.models import moe as M
 from repro.models.sharding import current_mesh
 
 Array = jax.Array
-
-# jax renamed shard_map's replication-check kwarg check_rep -> check_vma
-_CHECK_KWARG = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False})
 
 
 def _round_up(x: int, m: int) -> int:
@@ -84,14 +76,14 @@ def moe_ffn_ep(cfg: ModelConfig, p: Dict, x: Array,
     batch_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
 
     body = functools.partial(_ep_body, cfg, axis, m_sz)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch_axes if batch_axes else None, None, None),
                   P(None, None),                    # router replicated
                   P(axis), P(axis), P(axis)),       # experts sharded on E
         out_specs=(P(batch_axes if batch_axes else None, None, None),
                    P()),
-        **_CHECK_KWARG)
+        check_vma=False)
     y, aux = fn(x, p["router"], p["w_gate"].astype(x.dtype),
                 p["w_up"].astype(x.dtype), p["w_down"].astype(x.dtype))
     return y, aux

@@ -5,8 +5,11 @@ import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 @pytest.mark.slow
@@ -16,12 +19,13 @@ def test_dryrun_cell_produces_roofline_artifact(tmp_path):
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "mamba2-130m", "--shape", "decode_32k",
          "--mesh", "single", "--tag", "testrun"],
-        cwd="/root/repo", env=env, capture_output=True, text=True,
+        cwd=REPO, env=env, capture_output=True, text=True,
         timeout=560)
     assert out.returncode == 0, out.stderr[-3000:]
-    art = ("launch_artifacts/dryrun/"
-           "mamba2-130m__decode_32k__single@testrun.json")
-    r = json.load(open(art))
+    art = (REPO / "launch_artifacts" / "dryrun"
+           / "mamba2-130m__decode_32k__single@testrun.json")
+    with open(art) as f:
+        r = json.load(f)
     assert r["status"] == "ok"
     assert r["chips"] == 256
     rf = r["roofline"]
@@ -32,3 +36,4 @@ def test_dryrun_cell_produces_roofline_artifact(tmp_path):
     # decode is memory-bound on any sane reading of the hardware
     assert rf["dominant"] != "compute"
     os.remove(art)
+    art.with_suffix(".hlo.zst").unlink(missing_ok=True)

@@ -97,8 +97,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 import repro
 from repro.launch import hlocost
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((8,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("model",))
 def body(c, w):
     return c @ w, None                      # w sharded on contracting dim
 def f(x, ws):

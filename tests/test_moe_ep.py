@@ -31,8 +31,8 @@ from repro.models.params import init_tree
 from repro.models.sharding import sharding_ctx
 
 # 2 (data) x 4 (model) mesh; 8 experts -> 2 per model shard
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = smoke_config("olmoe-1b-7b").replace(
     num_experts=8, experts_per_token=2, capacity_factor=8.0,
     dtype="float32", param_dtype="float32")

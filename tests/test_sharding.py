@@ -33,8 +33,8 @@ import repro
 from jax.sharding import PartitionSpec as P
 from repro.models.sharding import sharding_ctx, spec_for, \
     recorded_fallbacks
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 with sharding_ctx(mesh):
     # divisible: sharded
     assert spec_for((16, 64), ("batch", "ffn")) == P("data", "model"), \
