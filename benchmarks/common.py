@@ -58,10 +58,15 @@ def write_csv(path: str) -> None:
         w.writerows(ROWS)
 
 
-def make_manager(scale: float = 0.02, overrides=None) -> FeedManager:
+def make_manager(scale: float = 0.02, overrides=None,
+                 persistent_cache: bool = True) -> FeedManager:
     """Reference tables + manager; every driver calls this before its
-    first compile, so it also places JAX's persistent compile cache."""
-    compile_cache.enable()
+    first compile, so it also places JAX's persistent compile cache —
+    or, with ``persistent_cache=False``, keeps the process off it."""
+    if persistent_cache:
+        compile_cache.enable()
+    else:
+        compile_cache.disable()
     store = RefStore()
     Q.make_reference_tables(store, scale=scale, seed=7,
                             scale_overrides=overrides)

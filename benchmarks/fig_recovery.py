@@ -227,7 +227,8 @@ def bench_kill_restart(base_dir: str, kills: int, total: int, batch: int,
          "largest WAL tail replayed on restart")
     rec = [r["recovery_s"] for r in rounds]
     emit(FIG, "recovery_max_s", max(rec), "s",
-         "resume() -> backlog re-stored, worst round")
+         "resume() -> backlog re-stored, worst round; every round resumes "
+         "in a fresh process (its compiles are in it)")
     emit(FIG, "recovery_mean_s", sum(rec) / len(rec), "s", "")
 
 

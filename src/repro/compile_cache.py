@@ -3,7 +3,9 @@
 Entry points call ``enable()`` before their first compile: chip_smoke.py
 and the benchmark drivers (through ``benchmarks/common.make_manager``).
 Importing the package never calls it, so the tests compile without a
-cache and stay silent.
+cache and stay silent.  JAX decides once per process, at its first
+compile, whether the process uses the cache, so ``enable()`` and
+``disable()`` only take effect before that compile.
 """
 
 from __future__ import annotations
@@ -32,3 +34,16 @@ def enable() -> str:
         jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return path
+
+
+def disable() -> None:
+    """Keep this process off the persistent cache, even where
+    ``JAX_COMPILATION_CACHE_DIR`` is set: for measurements whose subject
+    is the compile itself (the INSERT baseline of fig24)."""
+    jax.config.update("jax_enable_compilation_cache", False)
+
+
+def is_on() -> bool:
+    """Whether compiles in this process may be served from disk."""
+    return bool(jax.config.jax_enable_compilation_cache
+                and jax.config.jax_compilation_cache_dir)

@@ -77,8 +77,10 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
+import warnings
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro import compile_cache
 from repro.core import records
 from repro.core.compaction import CompactionJob, CompactionStats
 from repro.core.computing import ComputingRunner, ComputingSpec, \
@@ -437,6 +439,8 @@ class FeedHandle:
         # merged under the lock at worker exit; read lock-free by
         # _finalize() after join() proved all workers are gone
         self._retired_computing = ComputingStats()  # write-guarded-by: _lock
+        # INSERT baseline: compile seconds each statement paid, in order
+        self.statement_compile_s: List[float] = []  # write-guarded-by: _lock
 
     # ------------------------------------------------------------- lifecycle
     def stop(self) -> None:
@@ -1331,24 +1335,38 @@ class FeedManager:
     def _start_insert(self, cfg: FeedConfig, handle: FeedHandle) -> None:
         """Approach 1 (§5.2.1): an external program issuing repeated INSERT
         statements — every statement re-pays query compilation and job
-        distribution, i.e. NO predeploy cache: fresh jit per batch."""
+        distribution, i.e. NO predeploy cache: fresh jit per batch.
+
+        Each statement traces, lowers and compiles anew
+        (``PredeployCache(retrace=True)``).  JAX's persistent cache is
+        process-wide and would serve every statement after the first from
+        disk, so a process measuring this baseline keeps it off
+        (``compile_cache.disable()``, as fig24 does)."""
         spec = ComputingSpec(cfg.udf, cfg.batch_size, model="per_batch")
+        if compile_cache.is_on():
+            warnings.warn(
+                "INSERT baseline with JAX's persistent compilation cache "
+                "on: statements may be served from disk instead of paying "
+                "compilation", RuntimeWarning, stacklevel=3)
 
         def loop():
             try:
                 runner = ComputingRunner(spec, self.refstore,
-                                         PredeployCache())
+                                         PredeployCache(retrace=True))
                 handle.runners.append(runner)
                 for frame in handle.adapter.frames():
-                    runner.cache = PredeployCache()   # recompilation cost
+                    # recompilation cost
+                    runner.cache = PredeployCache(retrace=True)
                     out = runner.run(frame)
                     handle.storage.write(out)
+                    compile_s = runner.cache.stats()["compile_s"]
                     # _frame_rows, not len(): a dict frame's len() is its
                     # COLUMN count; take the handle lock — stats are also
                     # read/merged from the joining thread
                     with handle._lock:
                         handle.stats.frames_in += 1
                         handle.stats.records_in += _frame_rows(frame)
+                        handle.statement_compile_s.append(compile_s)
             except BaseException as e:
                 handle._note_worker_err(e)
 

@@ -39,7 +39,11 @@ def tree_signature(tree: Any) -> Tuple:
 class PredeployCache:
     """Executable cache keyed by (job name, operand signature)."""
 
-    def __init__(self):
+    def __init__(self, retrace: bool = False):
+        # retrace=True: every compile starts from a new function identity,
+        # so JAX's in-process trace/lowering/compile caches all miss and
+        # the compile is paid in full (the INSERT baseline, feed.py)
+        self.retrace = retrace
         self._cache: Dict[Tuple, Any] = {}  # guarded-by: _lock
         self._lock = threading.Lock()        # lock-name: predeploy
         self.compiles = 0                    # guarded-by: _lock
@@ -74,7 +78,8 @@ class PredeployCache:
         shapes = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
             if hasattr(x, "shape") else x, operands)
-        exe = jax.jit(fn).lower(*shapes).compile()
+        target = (lambda *a: fn(*a)) if self.retrace else fn
+        exe = jax.jit(target).lower(*shapes).compile()
         dt = time.perf_counter() - t0
         with self._lock:
             self._cache.setdefault(key, exe)
