@@ -23,9 +23,10 @@ R5 listener-under-lock subscriber callbacks (``# fires-listeners``
                        ``# listener-registry`` field) never run under a
                        held lock.
 R6 obs-under-lock      telemetry publication — histogram ``.observe()``
-                       and span ``.emit()`` — never runs under a strict
-                       (non-``blocking-ok``) lock; counters and gauges
-                       are lock-free and stay legal anywhere.
+                       and span ``.emit()`` / ``.span()`` — never runs
+                       under a strict (non-``blocking-ok``) lock;
+                       counters and gauges are lock-free and stay legal
+                       anywhere.
 
 The analyzer is pure stdlib ``ast`` + ``tokenize``: it never imports the
 code it scans.  Exit status 0 means a clean tree.
@@ -679,13 +680,14 @@ class Linter:
                            f"{block} under lock '{strict_held[-1]}'")
                 # R6 — telemetry publication under a strict lock: histogram
                 # .observe() takes the per-instrument 'metrics' lock and
-                # span .emit() can take 'trace-rings' on a thread's first
-                # emit; both must run after release (counter .inc() /
+                # span .emit() (and .span(), which emits on exit) can take
+                # 'trace-rings' on a thread's first emit; all must run
+                # after release (counter .inc() /
                 # gauge .set() are lock-free and stay legal anywhere).
                 # blocking-ok step locks are exempt (their inward edges to
                 # 'metrics'/'trace-rings' are declared in annotations.py).
                 if (isinstance(node.func, ast.Attribute)
-                        and node.func.attr in ("observe", "emit")):
+                        and node.func.attr in ("observe", "emit", "span")):
                     report("obs-under-lock", node.lineno,
                            f".{node.func.attr}() publishes telemetry under "
                            f"lock '{strict_held[-1]}'; record under the "

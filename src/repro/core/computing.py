@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.core import records
 from repro.core.enrich.queries import EnrichUDF
+from repro.core.obs import FeedObs
 from repro.core.predeploy import PredeployCache
 from repro.core.refdata import RefSnapshot, RefStore
 
@@ -74,12 +75,17 @@ class ComputingStats:
     invocations: int = 0
     records: int = 0
     parse_s: float = 0.0
+    parse_cpu_s: float = 0.0     # the worker thread's CPU time in parse
     upload_s: float = 0.0
     convert_s: float = 0.0       # batch H2D + enriched-output D2H
     state_s: float = 0.0
     apply_s: float = 0.0
     state_builds: int = 0
     state_reuses: int = 0
+    # the worker around the runner: waiting for a frame from its holder,
+    # and blocked pushing the enriched batch downstream (feed.py)
+    wait_input_s: float = 0.0
+    wait_output_s: float = 0.0
     # stage-timing calibration passes taken (fused chains only); the
     # calibration walls themselves are NOT in apply_s — they price the
     # attribution, not the feed
@@ -114,13 +120,23 @@ class ComputingSpec:
 
 
 class ComputingRunner:
-    """One runner per computing-job worker.  Thread-confined."""
+    """One runner per computing-job worker.  Thread-confined.
+
+    Each phase of a batch is measured by one ``obs.span`` (core/obs),
+    whose duration feeds the phase's ``ComputingStats`` counter and,
+    when the feed traces, a ``compute.<phase>`` span under the enclosing
+    ``apply.<group>``: ``compute.parse``, ``compute.upload``,
+    ``compute.h2d``, ``compute.state``, ``compute.execute``,
+    ``compute.d2h``."""
 
     def __init__(self, spec: ComputingSpec, refstore: RefStore,
-                 cache: Optional[PredeployCache] = None):
+                 cache: Optional[PredeployCache] = None,
+                 obs: Optional[FeedObs] = None):
         self.spec = spec
         self.refstore = refstore
         self.cache = cache or PredeployCache()
+        self.obs = obs if obs is not None else FeedObs()
+        self._parent = 0      # span id of the apply.<group> being run
         self.stats = ComputingStats()
         self._device_refs: Dict[str, Tuple[int, Dict[str, jax.Array]]] = {}
         self._state = None            # (versions, state) for stream/gated
@@ -153,20 +169,21 @@ class ComputingRunner:
         once — the paper's compile-once/invoke-many contract still holds
         per shape."""
         out = {}
-        t0 = time.perf_counter()
         force = self.spec.refresh == "always" and self.spec.model != "stream"
         q = self.TRIM_QUANTUM
-        for name, snap in snaps.items():
-            hit = self._device_refs.get(name)
-            if hit is not None and hit[0] == snap.version and not force:
-                out[name] = hit[1]
-                continue
-            n = min(snap.capacity,
-                    ((max(snap.size, 1) + q - 1) // q) * q)
-            dev = {k: jnp.asarray(v[:n]) for k, v in snap.arrays.items()}
-            self._device_refs[name] = (snap.version, dev)
-            out[name] = dev
-        self.stats.upload_s += time.perf_counter() - t0
+        with self.obs.span("compute.upload", parent=self._parent) as sp:
+            for name, snap in snaps.items():
+                hit = self._device_refs.get(name)
+                if hit is not None and hit[0] == snap.version and not force:
+                    out[name] = hit[1]
+                    continue
+                n = min(snap.capacity,
+                        ((max(snap.size, 1) + q - 1) // q) * q)
+                dev = {k: jnp.asarray(v[:n])
+                       for k, v in snap.arrays.items()}
+                self._device_refs[name] = (snap.version, dev)
+                out[name] = dev
+        self.stats.upload_s += sp.dur
         return out
 
     # ----------------------------------------------------------------- state
@@ -196,11 +213,12 @@ class ComputingRunner:
                 self.stats.state_reuses += 1
                 states.append(prev[1])
                 continue
-            t0 = time.perf_counter()
-            state = self.cache.invoke(f"state:{udf.name}:{stage.name}",
-                                      stage.state_fn, refs)
-            state = jax.block_until_ready(state)
-            dt = time.perf_counter() - t0
+            with self.obs.span("compute.state", parent=self._parent,
+                               stage=stage.name) as sp:
+                state = self.cache.invoke(
+                    f"state:{udf.name}:{stage.name}", stage.state_fn, refs)
+                state = jax.block_until_ready(state)
+            dt = sp.dur
             ss.state_builds += 1
             ss.state_s += dt
             self.stats.state_builds += 1
@@ -221,10 +239,11 @@ class ComputingRunner:
         if reuse:
             self.stats.state_reuses += 1
             return self._state
-        t0 = time.perf_counter()
-        state = self.cache.invoke(f"state:{udf.name}", udf.build_state, refs)
-        state = jax.block_until_ready(state)
-        self.stats.state_s += time.perf_counter() - t0
+        with self.obs.span("compute.state", parent=self._parent) as sp:
+            state = self.cache.invoke(f"state:{udf.name}", udf.build_state,
+                                      refs)
+            state = jax.block_until_ready(state)
+        self.stats.state_s += sp.dur
         self.stats.state_builds += 1
         self._state = state
         self._state_versions = versions
@@ -236,28 +255,36 @@ class ComputingRunner:
         that arrive pre-parsed from a balanced intake).  Coalesced
         micro-batches exceeding the configured batch size are padded up to a
         power-of-two row bucket so the predeployed executables see a bounded
-        set of shapes instead of one compile per coalesced size."""
-        t0 = time.perf_counter()
-        if isinstance(frame, dict):
-            batch = frame
-        else:
-            batch = records.parse_json_lines(frame)
-        size = self.spec.batch_size
-        n = records.batch_rows(batch)
-        if n > size and self.spec.model != "per_record":
-            # per_record keeps pad_batch's loud oversize assert: its row
-            # loop walks exactly batch_size rows, so a bucketed batch
-            # would silently drop the tail
-            from repro.core.enrich import dispatch
-            size = dispatch.bucket_rows(n, minimum=size)
-        batch = records.pad_batch(batch, size)
-        self.stats.parse_s += time.perf_counter() - t0
+        set of shapes instead of one compile per coalesced size.
+        ``parse_cpu_s`` takes the thread's own CPU time of the same
+        stretch, so ``parse_s - parse_cpu_s`` is time spent not running
+        (waiting for the GIL or the CPU)."""
+        with self.obs.span("compute.parse", parent=self._parent,
+                           cpu=True) as sp:
+            if isinstance(frame, dict):
+                batch = frame
+            else:
+                batch = records.parse_json_lines(frame)
+            size = self.spec.batch_size
+            n = records.batch_rows(batch)
+            if n > size and self.spec.model != "per_record":
+                # per_record keeps pad_batch's loud oversize assert: its
+                # row loop walks exactly batch_size rows, so a bucketed
+                # batch would silently drop the tail
+                from repro.core.enrich import dispatch
+                size = dispatch.bucket_rows(n, minimum=size)
+            batch = records.pad_batch(batch, size)
+        self.stats.parse_s += sp.dur
+        self.stats.parse_cpu_s += sp.cpu
         return batch
 
     # ------------------------------------------------------------------- run
-    def run(self, frame) -> Dict[str, np.ndarray]:
+    def run(self, frame, parent: int = 0) -> Dict[str, np.ndarray]:
         """One computing-job invocation: returns the enriched batch
-        (original columns + UDF outputs + valid mask), as numpy."""
+        (original columns + UDF outputs + valid mask), as numpy.
+        ``parent`` is the id of the span the caller measures the
+        invocation with; the phases' spans name it."""
+        self._parent = parent
         batch = self.parse(frame)
         nvalid = int(batch["valid"].sum())
         udf = self.spec.udf
@@ -272,9 +299,9 @@ class ComputingRunner:
         refs = self._refs_to_device(snaps)
         apply_before = self.stats.apply_s
 
-        t0 = time.perf_counter()
-        dev_batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        self.stats.convert_s += time.perf_counter() - t0
+        with self.obs.span("compute.h2d", parent=parent) as sp:
+            dev_batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        self.stats.convert_s += sp.dur
         if self.spec.model == "per_record":
             enriched = self._run_per_record(dev_batch, refs, versions)
         else:
@@ -282,17 +309,18 @@ class ComputingRunner:
                 state = self._get_staged_state(refs, snaps)
             else:
                 state = self._get_state(refs, versions)
-            t0 = time.perf_counter()
-            enriched = self.cache.invoke(
-                f"apply:{udf.name}", udf.apply_fn, dev_batch, state, refs)
-            enriched = jax.block_until_ready(enriched)
-            self.stats.apply_s += time.perf_counter() - t0
+            with self.obs.span("compute.execute", parent=parent) as sp:
+                enriched = self.cache.invoke(
+                    f"apply:{udf.name}", udf.apply_fn, dev_batch, state,
+                    refs)
+                enriched = jax.block_until_ready(enriched)
+            self.stats.apply_s += sp.dur
 
         out = dict(batch)
-        t0 = time.perf_counter()
-        for k, v in enriched.items():
-            out[k] = np.asarray(v)
-        self.stats.convert_s += time.perf_counter() - t0
+        with self.obs.span("compute.d2h", parent=parent) as sp:
+            for k, v in enriched.items():
+                out[k] = np.asarray(v)
+        self.stats.convert_s += sp.dur
         self.stats.invocations += 1
         self.stats.records += nvalid
         stages = udf.stages or (udf,)

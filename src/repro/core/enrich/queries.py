@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -361,21 +362,30 @@ def chain(name: str, *udfs: EnrichUDF) -> EnrichUDF:
     granularity), outputs merged; later UDFs see earlier outputs in the
     batch (SQL++ LET-style).  The fused ``apply_fn`` runs the whole chain in
     a single jit / predeployed executable — one kernel dispatch per batch
-    instead of one per stage.  Nested chains flatten."""
+    instead of one per stage.  Nested chains flatten.  Each stage traces
+    under ``jax.named_scope(<stage name>)``, so a device profile of the
+    fused executable attributes its operations per stage."""
     flat: Tuple[EnrichUDF, ...] = tuple(
         s for u in udfs for s in (u.stages or (u,)))
     tables = tuple(dict.fromkeys(t for u in flat for t in u.ref_tables))
     has_state = any(u.state_fn is not None for u in flat)
 
     def state_fn(refs):
-        return tuple(u.state_fn(refs) if u.state_fn is not None else ()
-                     for u in flat)
+        states = []
+        for u in flat:
+            if u.state_fn is None:
+                states.append(())
+                continue
+            with jax.named_scope(u.name):
+                states.append(u.state_fn(refs))
+        return tuple(states)
 
     def apply_fn(batch, state, refs):
         out = {}
         cur = dict(batch)
         for u, s in zip(flat, state):
-            res = u.apply_fn(cur, s, refs)
+            with jax.named_scope(u.name):
+                res = u.apply_fn(cur, s, refs)
             out.update(res)
             cur.update(res)
         return out

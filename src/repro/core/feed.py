@@ -121,28 +121,28 @@ def _store_consumer(storage: StorageJob, ledger=None, obs=None) -> Callable:
 
     Currency accounting (core/obs): once the write returns the rows are
     snapshot-queryable, so this is where store-visible latency — the
-    paper's lag metric, intake stamp to queryable — lands in the
-    ``ingest_visible_latency_s`` histogram, and where the ``store.append``
-    span closes a traced batch's journey.  Both happen with no lock held
-    (this thread is the sink holder's drain loop)."""
+    paper's lag metric, from the first line of the batch's frame read at
+    intake (``t_open``, so a frame's fill counts) to queryable — lands in
+    the ``ingest_visible_latency_s`` histogram, and where the
+    ``store.append`` span closes a traced batch's journey.  Both happen
+    with no lock held (this thread is the sink holder's drain loop)."""
     lat_hist = (obs.registry.histogram("ingest_visible_latency_s")
                 if obs is not None else None)
 
     def consume(frame) -> None:
         if isinstance(frame, _StoreBatch):
-            t0 = time.perf_counter()
-            storage.write(frame.batch, lineage=frame.lineage,
-                          span_ids=frame.span_ids)
+            if obs is None:
+                storage.write(frame.batch, lineage=frame.lineage,
+                              span_ids=frame.span_ids)
+            else:
+                with obs.span("store.append", frame.span_ids,
+                              rows=_frame_rows(frame.batch)):
+                    storage.write(frame.batch, lineage=frame.lineage,
+                                  span_ids=frame.span_ids)
             if ledger is not None and frame.wal_seqs:
                 ledger.mark_done(frame.wal_seqs)
-            if obs is not None:
-                dur = time.perf_counter() - t0
-                now = time.monotonic()
-                if frame.t_intake:
-                    lat_hist.observe(max(0.0, now - frame.t_intake))
-                if frame.span_ids:
-                    obs.emit("store.append", frame.span_ids, t0=now - dur,
-                             dur=dur, rows=_frame_rows(frame.batch))
+            if obs is not None and frame.t_open:
+                lat_hist.observe(max(0.0, time.monotonic() - frame.t_open))
         else:
             storage.write(frame)
             if ledger is not None:
@@ -162,20 +162,20 @@ class _StoreBatch:
     repair subsystem (core/repair.py) can find stale rows later.  On
     durable feeds ``wal_seqs`` carries the intake-log sequence numbers of
     the raw frames this batch was parsed from (core/durability.py);
-    ``span_ids``/``t_intake`` are the observability stamps lifted off the
-    raw ``TrackedFrame`` the same way (core/obs — span ids close the
-    trace at the store, the intake timestamp prices store-visible
-    latency)."""
-    __slots__ = ("batch", "lineage", "wal_seqs", "span_ids", "t_intake")
+    ``span_ids``/``t_open`` are the observability stamps lifted off the
+    raw ``TrackedFrame`` the same way (core/obs — span ids close the trace
+    at the store, the time the frame's first line was read prices
+    store-visible latency)."""
+    __slots__ = ("batch", "lineage", "wal_seqs", "span_ids", "t_open")
 
     def __init__(self, batch: Dict, lineage: Optional[Dict[str, int]],
                  wal_seqs: Optional[Tuple[int, ...]] = None,
-                 span_ids: Tuple[int, ...] = (), t_intake: float = 0.0):
+                 span_ids: Tuple[int, ...] = (), t_open: float = 0.0):
         self.batch = batch
         self.lineage = lineage
         self.wal_seqs = wal_seqs
         self.span_ids = span_ids
-        self.t_intake = t_intake
+        self.t_open = t_open
 
 
 @dataclasses.dataclass
@@ -691,10 +691,18 @@ class FeedHandle:
             "computing_calibrations": comp.calibrations})
         reg.set_gauges({
             "computing_parse_s": comp.parse_s,
+            "computing_parse_cpu_s": comp.parse_cpu_s,
             "computing_upload_s": comp.upload_s,
             "computing_convert_s": comp.convert_s,
             "computing_state_s": comp.state_s,
-            "computing_apply_s": comp.apply_s})
+            "computing_apply_s": comp.apply_s,
+            "worker_wait_input_s": comp.wait_input_s,
+            "worker_wait_output_s": comp.wait_output_s})
+        intake = self.intake
+        if intake is not None:
+            reg.set_gauges({"intake_draw_s": intake.draw_s,
+                            "intake_fill_s": intake.fill_s,
+                            "intake_wait_output_s": intake.wait_output_s})
         for sname, ss in comp.per_stage.items():
             reg.set_gauges({mangle(f"stage_{sname}_apply_s"): ss.apply_s})
             reg.set_counters(
@@ -818,7 +826,7 @@ class FeedHandle:
         holder = PartitionHolder((group.job, pid), self.cfg.holder_capacity)
         self.manager.holder_manager.register(holder)
         runner = ComputingRunner(group.spec, self.manager.refstore,
-                                 self.manager.predeploy)
+                                 self.manager.predeploy, obs=self.obs)
         slot = _WorkerSlot(pid, holder, runner)
         w = threading.Thread(target=self._worker_loop, args=(group, slot),
                              name=f"{self.cfg.name}-{group.name}-{pid}",
@@ -859,13 +867,16 @@ class FeedHandle:
             self.stats.coalesced_frames += len(group) - 1
         seqs: List[int] = []
         sids: List[int] = []
-        t_old = 0.0
+        t_old = t_open = 0.0
         for g in group:
             seqs.extend(getattr(g, "wal_seqs", None) or ())
             sids.extend(getattr(g, "span_ids", ()))
             ti = getattr(g, "t_intake", 0.0)
             if ti and (not t_old or ti < t_old):
                 t_old = ti       # oldest stamp: latency covers the whole
+            to = getattr(g, "t_open", 0.0)
+            if to and (not t_open or to < t_open):
+                t_open = to
         if sids:
             # the coalesced batch covers every merged frame's WAL records
             # AND trace spans — the stamp unions ride to the sink; the
@@ -879,16 +890,18 @@ class FeedHandle:
             merged_b = records.concat_batches(group)
             if seqs or sids or t_old:
                 return TrackedBatch(merged_b, tuple(seqs), tuple(sids),
-                                    t_old)
+                                    t_old, t_open)
             return merged_b
         merged: List = []
         for g in group:
             merged.extend(g)
         if seqs or sids or t_old:
-            return TrackedFrame(merged, tuple(seqs), tuple(sids), t_old)
+            return TrackedFrame(merged, tuple(seqs), tuple(sids), t_old,
+                                t_open)
         return merged
 
-    def _run_with_retry(self, runner: ComputingRunner, frame) -> Dict:
+    def _run_with_retry(self, runner: ComputingRunner, frame,
+                        parent: int = 0) -> Dict:
         attempt = 0
         while True:
             with self._lock:
@@ -898,7 +911,7 @@ class FeedHandle:
                 if self.cfg.fault_hook is not None and \
                         self.cfg.fault_hook(inv):
                     raise RuntimeError(f"injected fault @ invocation {inv}")
-                return runner.run(frame)
+                return runner.run(frame, parent)
             except Exception:
                 attempt += 1
                 if attempt > self.cfg.max_retries:
@@ -907,28 +920,46 @@ class FeedHandle:
                     self.stats.retries += 1
                 time.sleep(self.cfg.retry_backoff_s * (2 ** (attempt - 1)))
 
+    def _next_frame(self, group: _StageGroupRuntime, slot: _WorkerSlot):
+        """The worker's next frame: pulled from its own holder or, while
+        that is idle or drained, stolen from the deepest backlog; None
+        once its queue holds the StopRecord and nothing can be stolen."""
+        holder = slot.holder
+        while True:
+            frame = holder.pull(timeout=0.05)
+            if frame is not None and not isinstance(frame, StopRecord):
+                return frame
+            # idle or our queue drained: try stealing a backlog — never
+            # while retiring (the point is to shed capacity)
+            stolen = None
+            if self.cfg.work_stealing and not slot.retire.is_set():
+                deep = self.manager.holder_manager.deepest(
+                    group.job, exclude=slot.pid)
+                if deep is not None and deep.depth > 1:
+                    stolen = deep.steal()
+            if stolen is not None:
+                with self._lock:
+                    self.stats.steals += 1
+                return stolen
+            if isinstance(frame, StopRecord):
+                return None
+
     def _worker_loop(self, group: _StageGroupRuntime,
                      slot: _WorkerSlot) -> None:
+        """One computing worker.  Each batch is three measured stretches
+        (core/obs spans, each also an always-on counter of the runner's
+        ``ComputingStats``): ``worker.wait_input`` until a frame comes,
+        ``apply.<group>`` (the runner's ``compute.*`` phases nest under
+        it) and ``worker.wait_output``, blocked handing the batch on."""
         pid, holder, runner = slot.pid, slot.holder, slot.runner
+        obs = self.obs
         try:
             while True:
-                frame = holder.pull(timeout=0.05)
-                if frame is None or isinstance(frame, StopRecord):
-                    # idle or our queue drained: try stealing a backlog —
-                    # never while retiring (the point is to shed capacity)
-                    stolen = None
-                    if self.cfg.work_stealing and not slot.retire.is_set():
-                        deep = self.manager.holder_manager.deepest(
-                            group.job, exclude=pid)
-                        if deep is not None and deep.depth > 1:
-                            stolen = deep.steal()
-                    if stolen is None:
-                        if isinstance(frame, StopRecord):
-                            return
-                        continue
-                    frame = stolen
-                    with self._lock:
-                        self.stats.steals += 1
+                with obs.span("worker.wait_input") as sp:
+                    frame = self._next_frame(group, slot)
+                runner.stats.wait_input_s += sp.dur
+                if frame is None:
+                    return
                 if self._sinks_dead:
                     # no live sink: computing would silently discard the
                     # output anyway — drain frames without enriching so
@@ -942,6 +973,7 @@ class FeedHandle:
                 wal_seqs = getattr(frame, "wal_seqs", None)
                 span_ids = getattr(frame, "span_ids", ())
                 t_intake = getattr(frame, "t_intake", 0.0)
+                t_open = getattr(frame, "t_open", 0.0)
                 # backlog sampling happens on EVERY pull, controller or
                 # not — this is what makes backlog_p95_rows report for
                 # static feeds (it used to be elasticity-only)
@@ -950,67 +982,72 @@ class FeedHandle:
                 if t_intake:
                     self._backlog_age_hist.observe(
                         max(0.0, time.monotonic() - t_intake))
-                t0 = time.perf_counter()
-                out = self._run_with_retry(runner, frame)
-                apply_dt = time.perf_counter() - t0
-                holder.record_service(apply_dt)
-                if span_ids:
-                    self.obs.emit(f"apply.{group.name}", span_ids,
-                                  t0=time.monotonic() - apply_dt,
-                                  dur=apply_dt, partition=pid)
-                if group.next is not None:
-                    # intermediate stage group: hand the enriched batch to
-                    # the next group's holders, not the sinks — re-wrapped
-                    # so the obs/WAL stamps survive the hop and the next
-                    # group's apply span joins the same journey
-                    if wal_seqs or span_ids or t_intake:
-                        out = TrackedBatch(out, wal_seqs, span_ids,
-                                           t_intake)
-                    self._push_downstream(group, out)
-                    continue
-                out = self._project(out)
-                # fan-out: every sink holder gets every batch exactly once;
-                # the store sink's copy is tagged with the ref-version
-                # lineage the batch was enriched under (repair subsystem)
-                lineage = runner.last_versions
-                delivered = 0
-                for si, sh in enumerate(self.sink_holders):
-                    if sh.error is not None:
-                        # sink consumer raised: its holder closed itself
-                        # (fail-fast drain); keep feeding the healthy
-                        # sinks — the error is re-raised by join()
-                        continue
-                    try:
-                        if si == self._store_sink_idx and \
-                                (lineage is not None or wal_seqs or
-                                 span_ids or t_intake):
-                            sh.push(_StoreBatch(out, lineage, wal_seqs,
-                                                span_ids, t_intake))
-                        elif span_ids or t_intake:
-                            # tee sinks get the same dict payload wrapped
-                            # with the obs stamps so their sink.append
-                            # spans carry ids — a slow tee then shows up
-                            # in the critical-path profile by name
-                            sh.push(TrackedBatch(out, None, span_ids,
-                                                 t_intake))
-                        else:
-                            sh.push(out)
-                        delivered += 1
-                    except RuntimeError:
-                        if sh.error is None:     # not a sink failure
-                            raise
-                if delivered == 0 and self.sink_holders:
-                    # every sink is dead: stop the adapter and switch to
-                    # discard-drain (below) so the stop protocol still
-                    # completes; the sink error surfaces from join()
-                    self._sinks_dead = True
-                    self.adapter.stop()
+                with obs.span(f"apply.{group.name}", span_ids,
+                              partition=pid) as ap:
+                    out = self._run_with_retry(runner, frame, ap.id)
+                with obs.span("worker.wait_output") as sp:
+                    self._deliver(group, runner, out, wal_seqs, span_ids,
+                                  t_intake, t_open)
+                runner.stats.wait_output_s += sp.dur
         except BaseException as e:
             # feedlint R1 fix: error collection races join()'s liveness
             # checks without the lock (inside _note_worker_err)
             self._note_worker_err(e)
         finally:
             self._on_worker_exit(group, slot)
+
+    def _deliver(self, group: _StageGroupRuntime, runner: ComputingRunner,
+                 out: Dict, wal_seqs, span_ids, t_intake: float,
+                 t_open: float) -> None:
+        """Hand an enriched batch on: to the next stage group's holders,
+        or to every sink holder; blocks while the target is full."""
+        if group.next is not None:
+            # intermediate stage group: hand the enriched batch to the
+            # next group's holders, not the sinks — re-wrapped so the
+            # obs/WAL stamps survive the hop and the next group's apply
+            # span joins the same journey
+            if wal_seqs or span_ids or t_intake:
+                out = TrackedBatch(out, wal_seqs, span_ids, t_intake,
+                                   t_open)
+            self._push_downstream(group, out)
+            return
+        out = self._project(out)
+        # fan-out: every sink holder gets every batch exactly once; the
+        # store sink's copy is tagged with the ref-version lineage the
+        # batch was enriched under (repair subsystem)
+        lineage = runner.last_versions
+        delivered = 0
+        for si, sh in enumerate(self.sink_holders):
+            if sh.error is not None:
+                # sink consumer raised: its holder closed itself
+                # (fail-fast drain); keep feeding the healthy sinks —
+                # the error is re-raised by join()
+                continue
+            try:
+                if si == self._store_sink_idx and \
+                        (lineage is not None or wal_seqs or
+                         span_ids or t_open):
+                    sh.push(_StoreBatch(out, lineage, wal_seqs,
+                                        span_ids, t_open))
+                elif span_ids or t_intake:
+                    # tee sinks get the same dict payload wrapped with
+                    # the obs stamps so their sink.append spans carry
+                    # ids — a slow tee then shows up in the
+                    # critical-path profile by name
+                    sh.push(TrackedBatch(out, None, span_ids, t_intake,
+                                         t_open))
+                else:
+                    sh.push(out)
+                delivered += 1
+            except RuntimeError:
+                if sh.error is None:     # not a sink failure
+                    raise
+        if delivered == 0 and self.sink_holders:
+            # every sink is dead: stop the adapter and switch to
+            # discard-drain (above) so the stop protocol still
+            # completes; the sink error surfaces from join()
+            self._sinks_dead = True
+            self.adapter.stop()
 
     def _push_downstream(self, group: _StageGroupRuntime, out: Dict) -> None:
         """Round-robin an enriched batch into the next stage group's live

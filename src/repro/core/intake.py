@@ -26,6 +26,7 @@ import threading
 import time
 from typing import Iterator, List, Optional, Tuple
 
+from repro.core.obs import FeedObs
 from repro.core.partition_holder import PartitionHolder
 from repro.core.records import SyntheticTweets, batch_rows
 
@@ -46,19 +47,23 @@ class TrackedFrame(list):
 
     The observability layer (core/obs) rides the same vehicle:
     ``span_ids`` are the trace span ids stamped at intake (coalescing
-    unions them), and ``t_intake`` is the monotonic intake timestamp
-    that store-visible latency (``ingest_visible_latency_s``) is
-    measured from.  Both default empty/0 so WAL- and recovery-built
-    frames are unchanged."""
+    unions them), ``t_intake`` is the monotonic time the intake finished
+    drawing the frame (what holder queueing, ``holder_backlog_age_s``,
+    is measured from), and ``t_open`` the monotonic time its first line
+    was read (what store-visible latency, ``ingest_visible_latency_s``,
+    is measured from, so a frame's fill counts).  All default empty/0
+    so WAL- and recovery-built frames are unchanged."""
 
-    __slots__ = ("wal_seqs", "span_ids", "t_intake")
+    __slots__ = ("wal_seqs", "span_ids", "t_intake", "t_open")
 
     def __init__(self, lines, wal_seqs: Tuple[int, ...] = (),
-                 span_ids: Tuple[int, ...] = (), t_intake: float = 0.0):
+                 span_ids: Tuple[int, ...] = (), t_intake: float = 0.0,
+                 t_open: float = 0.0):
         super().__init__(lines)
         self.wal_seqs = tuple(wal_seqs)
         self.span_ids = tuple(span_ids)
         self.t_intake = t_intake
+        self.t_open = t_open
 
 
 class TrackedBatch(dict):
@@ -76,14 +81,16 @@ class TrackedBatch(dict):
     end to end instead of dropping them at the intermediate holder
     hand-off."""
 
-    __slots__ = ("wal_seqs", "span_ids", "t_intake")
+    __slots__ = ("wal_seqs", "span_ids", "t_intake", "t_open")
 
     def __init__(self, batch, wal_seqs: Optional[Tuple[int, ...]] = None,
-                 span_ids: Tuple[int, ...] = (), t_intake: float = 0.0):
+                 span_ids: Tuple[int, ...] = (), t_intake: float = 0.0,
+                 t_open: float = 0.0):
         super().__init__(batch)
         self.wal_seqs = tuple(wal_seqs) if wal_seqs else None
         self.span_ids = tuple(span_ids)
         self.t_intake = t_intake
+        self.t_open = t_open
 
 
 class Adapter:
@@ -94,9 +101,15 @@ class Adapter:
     unit is adapter-defined: bytes for files, records for the synthetic
     stream).  ``resume(offset)`` positions a fresh instance so its
     ``frames()`` yields exactly the post-``offset`` remainder; the base
-    class declines (``resumable = False``)."""
+    class declines (``resumable = False``).
+
+    ``frame_t_open`` is the monotonic time the first line of the frame
+    last yielded was read, for adapters that assemble frames from a
+    stream (``SocketAdapter``); None where the adapter cannot tell, and
+    the intake then takes the start of the draw."""
 
     resumable = False
+    frame_t_open: Optional[float] = None
 
     def __init__(self):
         self._stop = threading.Event()
@@ -259,6 +272,7 @@ class SocketAdapter(Adapter):
             else:
                 return
             buf: List[bytes] = []
+            t_open = 0.0
             with conn, conn.makefile("rb") as f:
                 for line in f:
                     if self._stop.is_set():
@@ -266,11 +280,15 @@ class SocketAdapter(Adapter):
                     line = line.strip()
                     if not line:
                         continue
+                    if not buf:
+                        t_open = time.monotonic()
                     buf.append(line)
                     if len(buf) >= self.frame_size:
+                        self.frame_t_open = t_open
                         yield buf
                         buf = []
             if buf:
+                self.frame_t_open = t_open
                 yield buf
         finally:
             self._srv.close()
@@ -305,11 +323,19 @@ class IntakeJob(threading.Thread):
         self.holders = holders
         self.frames_in = 0
         self.records_in = 0
+        # always-on counters (single writer: this thread): drawing frames
+        # from the adapter, filling them (first line read to frame
+        # complete, inside the draw), and blocked pushing into a full
+        # holder
+        self.draw_s = 0.0
+        self.fill_s = 0.0
+        self.wait_output_s = 0.0
         self.closing = False     # guarded-by: _lock
         self.error: Optional[BaseException] = None
         self._wal = wal
         self._ledger = ledger
         self._obs = obs          # FeedObs (None for bare/test intakes)
+        self._spans = obs if obs is not None else FeedObs()
         self._wal_hist = (obs.registry.histogram("wal_append_s")
                           if obs is not None and wal is not None else None)
         # the decoupled path passes the feed-handle lock in, so
@@ -320,62 +346,41 @@ class IntakeJob(threading.Thread):
     def run(self) -> None:
         try:
             i = 0
-            t_last = time.perf_counter()
-            for frame in self.adapter.frames():
-                draw_s = time.perf_counter() - t_last
-                wal_s = None
-                if self._wal is not None and not isinstance(
-                        frame, (TrackedFrame, dict)):
-                    # write-ahead ack: log before any holder sees it
-                    off = getattr(self.adapter, "offset", 0)
-                    t_wal = time.perf_counter()
-                    seq = self._wal.append_frame(off, frame)
-                    wal_s = time.perf_counter() - t_wal
-                    self._ledger.note_logged(seq, off)
-                    frame = TrackedFrame(frame, (seq,))
-                if self._obs is not None:
-                    # currency stamp (always) + span ids (tracing only);
-                    # no lock is held here (feedlint R6 discipline).
-                    # Pre-parsed dict frames ride a TrackedBatch, raw
-                    # line frames a TrackedFrame — same stamps either way
-                    if isinstance(frame, dict):
-                        if not isinstance(frame, TrackedBatch):
-                            frame = TrackedBatch(frame)
-                        nrows = batch_rows(frame)
-                    else:
-                        if not isinstance(frame, TrackedFrame):
-                            frame = TrackedFrame(frame)
-                        nrows = len(frame)
-                    frame.t_intake = time.monotonic()
-                    if wal_s is not None:
-                        self._wal_hist.observe(wal_s)
-                    if self._obs.tracing:
-                        frame.span_ids = (self._obs.new_span(),)
-                        self._obs.emit("intake.draw", frame.span_ids,
-                                       t0=frame.t_intake, dur=draw_s,
-                                       rows=nrows)
-                        if wal_s is not None:
-                            self._obs.emit("wal.append", frame.span_ids,
-                                           t0=frame.t_intake, dur=wal_s,
-                                           rows=nrows)
-                while True:
-                    # snapshot the live holder list each frame (elasticity)
-                    hs = list(self.holders)
-                    target = hs[i % len(hs)]
-                    try:
-                        target.push(frame)
-                        break
-                    except RuntimeError:
-                        if not target.closed:
-                            raise
-                        # holder retired mid-push: re-target round-robin
+            frames = iter(self.adapter.frames())
+            spans = self._spans
+            while True:
+                with spans.span("intake.draw") as draw:
+                    frame = next(frames, None)
+                    if frame is not None and spans.tracing:
+                        draw.ids = (spans.new_span(),)
+                        draw.extra["rows"] = (
+                            batch_rows(frame) if isinstance(frame, dict)
+                            else len(frame))
+                self.draw_s += draw.dur
+                if frame is None:
+                    break
+                frame = self._stamp(frame, time.monotonic(), draw)
+                with spans.span("intake.wait_output") as sp:
+                    while True:
+                        # snapshot the live holder list each frame
+                        # (elasticity)
+                        hs = list(self.holders)
+                        target = hs[i % len(hs)]
+                        try:
+                            target.push(frame)
+                            break
+                        except RuntimeError:
+                            if not target.closed:
+                                raise
+                            # holder retired mid-push: re-target
+                            # round-robin
+                self.wait_output_s += sp.dur
                 i += 1
                 self.frames_in += 1
                 # dict frames arrive pre-parsed; len() would count COLUMNS
                 self.records_in += (batch_rows(frame)
                                     if isinstance(frame, dict)
                                     else len(frame))
-                t_last = time.perf_counter()
         except BaseException as e:
             self.error = e
         finally:
@@ -385,3 +390,50 @@ class IntakeJob(threading.Thread):
             for h in hs:                 # close OUTSIDE the lock: push of
                 if not h.closed:         # the StopRecord may block briefly
                     h.close()
+
+    def _stamp(self, frame, t_drawn: float, draw):
+        """Log a live frame to the WAL (durable feeds) and stamp it for
+        the observability layer: the draw's span ids, the time it was
+        drawn (``t_drawn``, monotonic) and the time its first line came
+        (``t_open``)."""
+        wal_s = None
+        if self._wal is not None and not isinstance(
+                frame, (TrackedFrame, dict)):
+            # write-ahead ack: log before any holder sees it
+            off = getattr(self.adapter, "offset", 0)
+            t_wal = time.perf_counter()
+            seq = self._wal.append_frame(off, frame)
+            wal_s = time.perf_counter() - t_wal
+            self._ledger.note_logged(seq, off)
+            frame = TrackedFrame(frame, (seq,))
+        obs = self._obs
+        if obs is None:
+            return frame
+        # currency stamps (always) + span ids (tracing only); no lock is
+        # held here (feedlint R6 discipline).  Pre-parsed dict frames ride
+        # a TrackedBatch, raw line frames a TrackedFrame — same stamps
+        # either way
+        if isinstance(frame, dict):
+            if not isinstance(frame, TrackedBatch):
+                frame = TrackedBatch(frame)
+        elif not isinstance(frame, TrackedFrame):
+            frame = TrackedFrame(frame)
+        frame.t_intake = (t_drawn if wal_s is None
+                          else time.monotonic())
+        # the draw's start stands in where the adapter cannot tell when
+        # the frame's first line came
+        t_open = (getattr(self.adapter, "frame_t_open", None)
+                  or t_drawn - draw.dur)
+        frame.t_open = t_open
+        fill = max(0.0, t_drawn - t_open)
+        self.fill_s += fill
+        if wal_s is not None:
+            self._wal_hist.observe(wal_s)
+        if obs.tracing:
+            frame.span_ids = draw.ids
+            rows = draw.extra.get("rows", 0)
+            obs.emit("intake.fill", t0=t_open, dur=fill, rows=rows)
+            if wal_s is not None:
+                obs.emit("wal.append", frame.span_ids,
+                         t0=frame.t_intake - wal_s, dur=wal_s, rows=rows)
+        return frame

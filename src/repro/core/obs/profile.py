@@ -176,13 +176,16 @@ class JourneyProfiler:
     def ingest(self, spans: List[Dict[str, Any]]) -> int:
         """Fold drained spans into the journey table; returns the number
         of spans that joined a journey (spans with no ids — repair,
-        compaction, checkpoint — only land in the ``/trace`` ring)."""
+        compaction, checkpoint — and child spans, which carry a
+        ``parent`` and split a hop already counted, such as the
+        ``compute.*`` phases of an ``apply.<group>``, only land in the
+        ``/trace`` ring)."""
         joined = 0
         with self._lock:
             for span in spans:
                 self._recent.append(span)
                 ids = span.get("spans") or ()
-                if not ids:
+                if not ids or span.get("parent"):
                     continue
                 root = None
                 for sid in ids:

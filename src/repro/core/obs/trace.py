@@ -2,10 +2,14 @@
 
 Every pipeline hop that touches a tracked batch emits one *span* — a
 small dict with a name from the taxonomy in docs/OBSERVABILITY.md
-(``intake.draw``, ``wal.append``, ``coalesce``, ``apply.<group>``,
-``sink.append``, ``store.append``, ``store.flush``, ``repair.unit``,
-``compact.merge``, ``checkpoint``), the frame's span ids, a monotonic
-start time, and a duration.  Span ids ride the frame intake→worker→store
+(``intake.draw``, ``intake.fill``, ``intake.wait_output``,
+``wal.append``, ``coalesce``, ``worker.wait_input``, ``apply.<group>``
+and its ``compute.*`` phases, ``worker.wait_output``, ``sink.append``,
+``store.append``, ``store.flush``, ``repair.unit``, ``compact.merge``,
+``checkpoint``), the frame's span ids, a monotonic start time, and a
+duration.  Spans measured with ``FeedObs.span`` also carry their own
+``id``, the thread's CPU seconds (``cpu``) and, for a phase of an
+enclosing span, its ``parent``.  Span ids ride the frame intake→worker→store
 on ``TrackedFrame``/``_StoreBatch`` exactly like ``wal_seqs`` do (PR 7),
 so one batch's whole journey reconstructs from the drained spans.
 

@@ -13,7 +13,7 @@ intake adapter — no unbounded queue growth anywhere (the paper's "queue with
 a limited size").
 
 Extras beyond the paper, used by the runtime layer:
-  * service-time EWMA + depth metrics per holder (straggler detection),
+  * depth and backlog metrics per holder (straggler detection),
   * ``steal()`` so idle computing workers can take work from the deepest
     queue (work stealing / straggler mitigation),
   * a ``StopRecord`` sentinel implementing the paper's §7.1 drain protocol.
@@ -75,7 +75,6 @@ class PartitionHolder:
         self.pulled = 0                     # write-guarded-by: _lock
         self.push_wait_s = 0.0              # write-guarded-by: _lock
         self.pull_wait_s = 0.0              # write-guarded-by: _lock
-        self.service_ewma_s = 0.0   # updated by consumers via record_service
 
     # ------------------------------------------------------------------ push
     def push(self, frame: Any, timeout: Optional[float] = None) -> bool:
@@ -185,10 +184,6 @@ class PartitionHolder:
         with self._lock:
             return self._closed
 
-    def record_service(self, seconds: float, alpha: float = 0.2) -> None:
-        self.service_ewma_s = (alpha * seconds
-                               + (1 - alpha) * self.service_ewma_s)
-
 
 class ActivePartitionHolder(PartitionHolder):
     """Push-mode holder: a worker thread drains the queue into ``consumer``.
@@ -213,18 +208,15 @@ class ActivePartitionHolder(PartitionHolder):
             if isinstance(frame, StopRecord):
                 return
             try:
-                t0 = time.perf_counter()
-                self._consumer(frame)
-                dt = time.perf_counter() - t0
-                self.record_service(dt)
-                if self._obs is not None:
-                    sids = getattr(frame, "span_ids", ())
-                    if sids:
-                        # consumer call and span emission both run with
-                        # no lock held (feedlint R3/R6 discipline)
-                        self._obs.emit("sink.append", sids,
-                                       t0=time.monotonic() - dt, dur=dt,
-                                       sink=self.holder_id[0])
+                sids = getattr(frame, "span_ids", ())
+                if self._obs is not None and sids:
+                    # consumer call and span emission both run with no
+                    # lock held (feedlint R3/R6 discipline)
+                    with self._obs.span("sink.append", sids,
+                                        sink=self.holder_id[0]):
+                        self._consumer(frame)
+                else:
+                    self._consumer(frame)
             except BaseException as e:   # surfaced by join()
                 self._err = e
                 # fail fast, don't deadlock: close + drain so producers

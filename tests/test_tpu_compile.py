@@ -78,3 +78,71 @@ def test_segment_sum_compiles_at_q6_segments(one_chip, dtype):
 def test_segment_topk_compiles_at_envelope_corner(one_chip):
     _compile(lambda v, s: segment_topk_pallas(v, s, 2048, 16), one_chip,
              ((1 << 18,), jnp.int32), ((1 << 18,), jnp.int32))
+
+
+def _kernel_instructions(text):
+    """The instructions of compiled HLO text that the benchmark's
+    ``KERNELS`` patterns match, by kernel."""
+    import re
+    from bench.run import KERNELS
+    found = set()
+    for line in text.splitlines():
+        line = line.strip()
+        if line.startswith("ROOT "):
+            line = line[len("ROOT "):]
+        for k, pat in KERNELS.items():
+            if re.search(pat, line):
+                found.add((k, line.split(" = ")[0]))
+    return found
+
+
+def test_fused_stage_scopes_leave_the_kernel_instructions_as_they_were(
+        one_chip, monkeypatch):
+    """A fused chain traces each stage under ``jax.named_scope``: the
+    device profile's op names gain the stage, and the instructions the
+    benchmark's roofline metrics find keep their names."""
+    import contextlib
+    import sys
+    import numpy as np
+    from repro.core import RefStore, records
+    from repro.core.enrich import queries as Q
+    from repro.kernels import dispatch_mode
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    for kernel in ("hash_probe", "segment_reduce"):
+        # compile the Mosaic kernels, not their interpret-mode emulation
+        monkeypatch.setattr(f"repro.kernels.{kernel}.ops.auto_interpret",
+                            lambda i: bool(i))
+    store = RefStore()
+    Q.make_reference_tables(store, scale=0.002, seed=7)
+    snaps = store.snapshot(("safety_levels", "persons", "facilities",
+                            "district_areas", "average_incomes"))
+    refs = {n: {k: np.asarray(v) for k, v in s.arrays.items()}
+            for n, s in snaps.items()}
+    batch = records.pad_batch(records.parse_json_lines(
+        records.SyntheticTweets(seed=1).raw_lines(256)), 256)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def compiled():
+        udf = Q.chain("q1q6", Q.Q1, Q.Q6)   # fresh functions: no jit cache
+        with dispatch_mode("pallas"):
+            state = on_chip(jax.eval_shape(udf.state_fn, refs))
+            return (jax.jit(udf.state_fn).lower(on_chip(refs))
+                    .compile().as_text()
+                    + jax.jit(udf.apply_fn).lower(
+                        on_chip(batch), state, on_chip(refs))
+                    .compile().as_text())
+
+    scoped = compiled()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = compiled()
+    assert 'op_name="jit(state_fn)/q6_tweet_context/' in scoped
+    assert "q6_tweet_context/" not in plain
+    kernels = _kernel_instructions(scoped)
+    assert {k for k, _ in kernels} == {"hash_probe", "segment_sum"}
+    assert kernels == _kernel_instructions(plain)
