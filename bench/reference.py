@@ -28,19 +28,74 @@ Q4_RADIUS, Q4_K = 1.5, 8
 Q5_RADIUS, Q5_K = 3.0, 3
 Q7_RADIUS, Q7_K = 3.0, 3
 TWO_MONTHS = 62 * 24 * 3600
-CHUNK = 256
-
-
-def _chunks(n: int):
-    for lo in range(0, n, CHUNK):
-        yield slice(lo, min(lo + CHUNK, n))
 
 
 def _d2(px, py, rx, ry, ft) -> np.ndarray:
-    """(b, R) squared distances, every step in ``ft``."""
-    dx = px.astype(ft)[:, None] - rx.astype(ft)[None, :]
-    dy = py.astype(ft)[:, None] - ry.astype(ft)[None, :]
+    """Squared distances of paired points, elementwise, every step in
+    ``ft``."""
+    dx = px.astype(ft) - rx.astype(ft)
+    dy = py.astype(ft) - ry.astype(ft)
     return (dx * dx + dy * dy).astype(ft)
+
+
+# The spatial joins look only at reference rows in the 3 x 3 grid cells
+# around a tweet, the cells being squares of side GRID_SIDE x radius over
+# the coordinates rounded to ``ft``.  That finds every pair the exact
+# search over all rows accepts.  Let u be ``ft``'s unit roundoff (half its
+# epsilon: 2**-24 for float32, 2**-8 for bfloat16) and d the exact
+# difference of two rounded coordinates.  The computed dx is d rounded, so
+# |d| <= |dx| (1 + u).  Rounding is monotone and the other square is not
+# negative, so an accepted pair has round(dx * dx) <= d2 <= r2, hence
+# dx * dx <= r2 (1 + u), with r2 = round(round(radius)**2) <= radius**2
+# (1 + u)**3.  So |d| <= radius (1 + u)**3, under 1.012 radius even in
+# bfloat16; a side of 1.25 radius leaves a fifth of a cell to spare, far
+# more than float64's rounding of coordinate / side can take.  Two points
+# less than a side apart lie in the same or in neighbouring cells.
+GRID_SIDE = 1.25
+PAIR_BLOCK = 1 << 22          # candidate pairs compared at a time
+
+
+def _pairs(t: Cols, ref: Cols, radius: float, ft):
+    """Blocks of candidate pairs (tweet index, row index, d2 in ``ft``):
+    each tweet against the rows of its 3 x 3 neighbouring cells, in
+    ascending tweet order; every pair within ``radius`` is among them."""
+    side = GRID_SIDE * radius
+    rx = ref["lat"].astype(ft).astype(np.float64)
+    ry = ref["lon"].astype(ft).astype(np.float64)
+    px = t["lat"].astype(ft).astype(np.float64)
+    py = t["lon"].astype(ft).astype(np.float64)
+    rcx, rcy = np.floor(rx / side), np.floor(ry / side)
+    if rcx.size == 0 or px.size == 0:
+        return
+    x0, y0 = rcx.min(), rcy.min()
+    nx, ny = int(rcx.max() - x0) + 1, int(rcy.max() - y0) + 1
+    cell = (rcx - x0).astype(np.int64) * ny + (rcy - y0).astype(np.int64)
+    rows = np.argsort(cell, kind="stable")
+    offs = np.zeros(nx * ny + 1, np.int64)
+    np.cumsum(np.bincount(cell, minlength=nx * ny), out=offs[1:])
+    # the tweets' 9 neighbouring cells, clipped to the rows' grid
+    tcx = (np.floor(px / side) - x0).astype(np.int64)
+    tcy = (np.floor(py / side) - y0).astype(np.int64)
+    step = np.array([-1, 0, 1])
+    cx = (tcx[:, None] + step[None, :])[:, :, None]
+    cy = (tcy[:, None] + step[None, :])[:, None, :]
+    ok = (cx >= 0) & (cx < nx) & (cy >= 0) & (cy < ny)
+    nb = np.where(ok, cx * ny + cy, 0).reshape(px.size, 9)
+    start = offs[nb]
+    count = np.where(ok.reshape(px.size, 9), offs[nb + 1] - start, 0)
+    per_tweet = np.cumsum(count.sum(1))
+    lo = 0
+    while lo < px.size:
+        base = per_tweet[lo - 1] if lo else 0
+        hi = max(lo + 1, int(np.searchsorted(per_tweet, base + PAIR_BLOCK,
+                                             side="right")))
+        c, s = count[lo:hi].ravel(), start[lo:hi].ravel()
+        ti = np.repeat(np.arange(lo, hi).repeat(9), c)
+        first = np.cumsum(c) - c
+        ri = rows[np.repeat(s - first, c) + np.arange(int(c.sum()))]
+        yield ti, ri, _d2(t["lat"][ti], t["lon"][ti], ref["lat"][ri],
+                          ref["lon"][ri], ft)
+        lo = hi
 
 
 def _nearest(t: Cols, tabs, table: str, radius: float, k: int, ft
@@ -48,24 +103,16 @@ def _nearest(t: Cols, tabs, table: str, radius: float, k: int, ft
     """Row index of up to ``k`` nearest rows within ``radius``, nearest
     first and lower row first among equals; -1 past the last."""
     ref = tabs[table]
-    n = t["lat"].shape[0]
-    out = np.full((n, k), -1, np.int64)
+    out = np.full((t["lat"].shape[0], k), -1, np.int64)
     r2 = ft(radius) * ft(radius)
-    kk = min(k, ref["lat"].shape[0])
-    for s in _chunks(n):
-        d2 = _d2(t["lat"][s], t["lon"][s], ref["lat"], ref["lon"],
-                 ft).astype(np.float64)
-        # rows at or below each row's k-th smallest distance, then
-        # ordered by (distance, row) and cut to k
-        kth = np.partition(d2, kk - 1, axis=1)[:, kk - 1:kk]
-        r, c = np.nonzero((d2 <= kth) & (d2 <= r2))
-        order = np.lexsort((c, d2[r, c], r))
-        r, c = r[order], c[order]
-        rank = np.arange(r.shape[0]) - np.searchsorted(r, r)
+    for ti, ri, d2 in _pairs(t, ref, radius, ft):
+        hit = d2 <= r2
+        ti, ri, d2 = ti[hit], ri[hit], d2[hit].astype(np.float64)
+        order = np.lexsort((ri, d2, ti))
+        ti, ri = ti[order], ri[order]
+        rank = np.arange(ti.shape[0]) - np.searchsorted(ti, ti)
         keep = rank < k
-        blk = out[s]
-        blk[r[keep], rank[keep]] = c[keep]
-        out[s] = blk
+        out[ti[keep], rank[keep]] = ri[keep]
     return out
 
 
@@ -74,16 +121,14 @@ def _within_count(t: Cols, tabs, table: str, radius: float, ft,
     ref = tabs[table]
     n = t["lat"].shape[0]
     r2 = ft(radius) * ft(radius)
-    out = np.zeros((n, max(groups, 1)), np.int32)
-    for s in _chunks(n):
-        hit = _d2(t["lat"][s], t["lon"][s], ref["lat"], ref["lon"], ft) <= r2
-        if groups:
-            r, c = np.nonzero(hit)
-            b = hit.shape[0]
-            out[s] = np.bincount(r * groups + ref[group][c],
-                                 minlength=b * groups).reshape(b, groups)
-        else:
-            out[s, 0] = hit.sum(1)
+    g = max(groups, 1)
+    out = np.zeros(n * g, np.int64)
+    for ti, ri, d2 in _pairs(t, ref, radius, ft):
+        hit = d2 <= r2
+        ti, ri = ti[hit], ri[hit]
+        key = ti * g + ref[group][ri] if groups else ti
+        out += np.bincount(key, minlength=n * g)
+    out = out.astype(np.int32).reshape(n, g)
     return out if groups else out[:, 0]
 
 
@@ -224,19 +269,19 @@ def q7(t: Cols, tabs, ft) -> Cols:
     rb, ev = tabs["religious_buildings"], tabs["attack_events"]
     idx = _nearest(t, tabs, "religious_buildings", Q7_RADIUS, Q7_K, ft)
     rels = np.where(idx >= 0, rb["religion"][np.maximum(idx, 0)], -1)
-    order = np.lexsort((ev["time"], ev["religion"]))
-    er, et = ev["religion"][order], ev["time"][order]
-    counts = np.zeros(rels.shape, np.int32)
-    ts = t["created_at"]
-    for j in range(rels.shape[1]):
-        r = rels[:, j]
-        lo_r = np.searchsorted(er, r, side="left")
-        hi_r = np.searchsorted(er, r, side="right")
-        for i in np.nonzero(r >= 0)[0]:
-            seg = et[lo_r[i]:hi_r[i]]
-            counts[i, j] = (np.searchsorted(seg, ts[i], side="left")
-                            - np.searchsorted(seg, ts[i] - TWO_MONTHS,
-                                              side="right"))
+    # one search over the events sorted by (religion, time): each key is
+    # religion * span + (time - base), with base and span wide enough
+    # that every tweet's window lies inside its religion's block
+    ts = t["created_at"].astype(np.int64)
+    et = ev["time"].astype(np.int64)
+    base = min(int(et.min(initial=0)), int(ts.min(initial=0)) - TWO_MONTHS)
+    span = max(int(et.max(initial=0)), int(ts.max(initial=0))) - base + 1
+    keys = np.sort(ev["religion"].astype(np.int64) * span + (et - base))
+    r = rels.astype(np.int64)
+    at = r * span + (ts[:, None] - base)
+    counts = (np.searchsorted(keys, at, side="left")
+              - np.searchsorted(keys, at - TWO_MONTHS, side="right"))
+    counts = np.where(rels >= 0, counts, 0).astype(np.int32)
     return {"nearby_religions": rels.astype(np.int32),
             "religion_attack_counts": counts}
 

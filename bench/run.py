@@ -128,14 +128,40 @@ class Ctx:
     trace: Optional[dict]             # bench.trace.reduce(...)
 
 
+# the counters the first readers were written against; each of them
+# keeps its name and value among all that ``_counters`` finds
+FIXED = ("parse_s", "upload_s", "convert_s", "state_s", "apply_s",
+         "invocations")
+
+
 def _counters(h) -> Dict[str, float]:
-    out = {"parse_s": 0.0, "upload_s": 0.0, "convert_s": 0.0,
-           "state_s": 0.0, "apply_s": 0.0, "invocations": 0}
+    """Every numeric counter the program keeps, as it stands; a metric
+    reads its rise over the window by name (``Ctx.window``).  Each
+    runner's ``ComputingStats`` field under its own name, summed over the
+    runners; each stage's ``StageStats`` field as
+    ``stage.<stage>.<field>``; the intake's as ``intake.<field>``; the
+    store's writes as ``store_write_s`` and ``store_batches``; and every
+    numeric counter and gauge of the feed's registry (``h.metrics()``)
+    under its registry name, where no name above has it."""
+    out: Dict[str, float] = {k: 0 for k in FIXED}
     for r in h.runners:
-        for k in out:
-            out[k] += getattr(r.stats, k)
+        for f in dataclasses.fields(r.stats):
+            v = getattr(r.stats, f.name)
+            if isinstance(v, (int, float)):
+                out[f.name] = out.get(f.name, 0) + v
+        for stage, ss in r.stats.per_stage.items():
+            for f in dataclasses.fields(ss):
+                key = f"stage.{stage}.{f.name}"
+                out[key] = out.get(key, 0) + getattr(ss, f.name)
+    intake = getattr(h, "intake", None)
+    for f in ("draw_s", "fill_s", "wait_output_s", "frames_in"):
+        if intake is not None and hasattr(intake, f):
+            out[f"intake.{f}"] = getattr(intake, f)
     out["store_write_s"] = h.storage.write_s
     out["store_batches"] = h.storage.batches
+    for k, v in h.metrics().items():
+        if isinstance(v, (int, float)) and not isinstance(v, bool):
+            out.setdefault(k, v)
     return out
 
 
@@ -262,7 +288,7 @@ def _run(cell, cfg, gen, seed, seconds, trace, control, rate, dump, jax):
     log(f"compared per enriched column (mismatches): {json.dumps(per_col)} "
         f"in {time.monotonic() - t_check:.1f} s")
     end_to_end = _end_to_end(cell, sched, vis, t0, seconds, setup_s)
-    d_win = {k: c1[k] - c0[k] for k in c0}
+    d_win = {k: c1.get(k, 0) - c0.get(k, 0) for k in {**c0, **c1}}
     result = {
         "correct": check.passed(checks),
         "attempted": offered,
