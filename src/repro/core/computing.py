@@ -45,18 +45,16 @@ class StageStats:
     """Per-stage observability for fused (chained) UDFs: how often each
     stage's intermediate state was rebuilt vs reused and what it cost.
     Inside a multi-stage fused executable apply time cannot be attributed
-    exactly per stage — the whole chain is ONE dispatch by design — so
-    ``apply_s`` splits the batch's apply wall across the fused stages by
-    **measured calibration fractions**: every ``CALIBRATE_EVERY``-th
-    batch the runner replays the chain stage-by-stage through per-stage
-    predeployed executables (compile excluded, off the hot path's
-    accounting) and blends the observed shares into an EWMA weight per
-    stage.  Until the first calibration lands the split is even — the
-    pre-calibration behavior, still exact when the executable holds a
-    single stage (the per-stage-split case the elasticity controller
-    samples; model="per_record" also keeps the even split).  Exact
-    *group*-level walls come from the tracer's ``apply.<group>`` spans
-    (core/obs, docs/OBSERVABILITY.md)."""
+    per stage on the host — the whole chain is ONE dispatch by design —
+    so ``apply_s`` splits the batch's apply wall evenly across the fused
+    stages; it is exact when the executable holds a single stage (the
+    per-stage-split case the elasticity controller samples).  Each fused
+    stage's device time comes from the device trace instead: every stage
+    is traced under ``jax.named_scope(<stage>)``, which a profile keeps
+    in each operation's name stack (``scopes`` of bench/trace.py, read by
+    ``bench/layer.stage_device_ms_per_batch``; docs/OBSERVABILITY.md).
+    Exact *group*-level walls come from the tracer's ``apply.<group>``
+    spans (core/obs)."""
     invocations: int = 0
     records: int = 0
     state_builds: int = 0
@@ -77,6 +75,7 @@ class ComputingStats:
     parse_s: float = 0.0
     parse_cpu_s: float = 0.0     # the worker thread's CPU time in parse
     upload_s: float = 0.0
+    upload_bytes: int = 0        # reference-table bytes put on the device
     convert_s: float = 0.0       # batch H2D + enriched-output D2H
     state_s: float = 0.0
     apply_s: float = 0.0
@@ -86,9 +85,9 @@ class ComputingStats:
     # and blocked pushing the enriched batch downstream (feed.py)
     wait_input_s: float = 0.0
     wait_output_s: float = 0.0
-    # stage-timing calibration passes taken (fused chains only); the
-    # calibration walls themselves are NOT in apply_s — they price the
-    # attribution, not the feed
+    # always 0: the stage-timing replay it counted is gone (a fused
+    # stage's device time comes from the trace, see StageStats); kept
+    # only because the benchmark's tests build it, until they drop it
     calibrations: int = 0
     # stage name -> StageStats, populated per enrichment stage (one entry
     # for a plain UDF, one per chained stage for a fused UDF)
@@ -149,10 +148,6 @@ class ComputingRunner:
         # fused UDFs: stage name -> (stage ref versions, state) so quiet
         # stages reuse their state while stale stages rebuild independently
         self._stage_states: Dict[str, Tuple[Tuple[int, ...], Any]] = {}
-        # measured per-stage apply-time fractions (EWMA over calibration
-        # passes); None until the first calibration -> even split
-        self._stage_weights: Optional[Dict[str, float]] = None
-        self._inv_since_cal = 0
 
     # ------------------------------------------------------------- snapshots
     TRIM_QUANTUM = 256
@@ -167,7 +162,8 @@ class ComputingRunner:
         round_up(size, 256) keeps shapes stable across small UPSERTs (the
         predeployed executable survives); crossing a quantum recompiles
         once — the paper's compile-once/invoke-many contract still holds
-        per shape."""
+        per shape.  ``upload_bytes`` counts the bytes of the trimmed
+        columns put on the device."""
         out = {}
         force = self.spec.refresh == "always" and self.spec.model != "stream"
         q = self.TRIM_QUANTUM
@@ -181,6 +177,8 @@ class ComputingRunner:
                         ((max(snap.size, 1) + q - 1) // q) * q)
                 dev = {k: jnp.asarray(v[:n])
                        for k, v in snap.arrays.items()}
+                self.stats.upload_bytes += sum(v.nbytes
+                                               for v in dev.values())
                 self._device_refs[name] = (snap.version, dev)
                 out[name] = dev
         self.stats.upload_s += sp.dur
@@ -323,71 +321,16 @@ class ComputingRunner:
         self.stats.convert_s += sp.dur
         self.stats.invocations += 1
         self.stats.records += nvalid
+        # a fused chain is ONE dispatch: its apply wall is split evenly
+        # across the stages (see the StageStats docstring)
         stages = udf.stages or (udf,)
-        # per-stage wall attribution: a fused chain is ONE dispatch, so
-        # this batch's apply wall is split across its stages by measured
-        # calibration fractions (even split until the first calibration;
-        # see the StageStats docstring)
-        weights = self._stage_weights
-        if len(stages) > 1 and self.spec.model != "per_record":
-            self._inv_since_cal += 1
-            # first calibration at the CALIBRATE_EVERY-th fused batch —
-            # NOT the first, so short feeds keep the strict one-dispatch
-            # profile (and its predeploy-cache footprint) unchanged
-            if self._inv_since_cal >= self.CALIBRATE_EVERY:
-                weights = self._calibrate_stages(stages, dev_batch,
-                                                 state, refs)
-                self._inv_since_cal = 0
-        batch_apply_s = self.stats.apply_s - apply_before
-        even = 1.0 / len(stages)
+        share = (self.stats.apply_s - apply_before) / len(stages)
         for st in stages:
-            frac = weights.get(st.name, even) if weights else even
             ss = self.stats.stage(st.name)
             ss.invocations += 1
             ss.records += nvalid
-            ss.apply_s += batch_apply_s * frac
+            ss.apply_s += share
         return out
-
-    # ------------------------------------------------------------ calibration
-    CALIBRATE_EVERY = 64     # fused-chain batches between stage re-timings
-
-    def _calibrate_stages(self, stages, dev_batch, state, refs
-                          ) -> Dict[str, float]:
-        """Time each fused stage individually — the chain replayed through
-        per-stage predeployed executables, outputs feeding forward exactly
-        like the fused ``apply_fn`` — and blend the observed shares into
-        the EWMA weights.  ``cache.get`` runs untimed first so a cold
-        executable's compile never pollutes the measured fraction, and
-        none of this wall lands in ``apply_s``: calibration prices the
-        *attribution*, not the feed.  Per-stage executables share the
-        predeploy cache with single-UDF feeds of the same stage (same
-        (name, fn, signature) key)."""
-        udf = self.spec.udf
-        states = (state if udf.stages and udf.state_fn is not None
-                  else ((),) * len(stages))
-        durs: Dict[str, float] = {}
-        cur = dict(dev_batch)
-        for st, s in zip(stages, states):
-            name = f"apply:{st.name}"
-            self.cache.get(name, st.apply_fn, cur, s, refs)
-            t0 = time.perf_counter()
-            res = self.cache.invoke(name, st.apply_fn, cur, s, refs)
-            res = jax.block_until_ready(res)
-            durs[st.name] = max(time.perf_counter() - t0, 1e-9)
-            cur.update(res)
-        total = sum(durs.values())
-        fresh = {n: d / total for n, d in durs.items()}
-        prev = self._stage_weights
-        if prev is None:
-            weights = fresh
-        else:
-            weights = {n: 0.5 * prev.get(n, f) + 0.5 * f
-                       for n, f in fresh.items()}
-            norm = sum(weights.values())
-            weights = {n: w / norm for n, w in weights.items()}
-        self._stage_weights = weights
-        self.stats.calibrations += 1
-        return weights
 
     def _run_per_record(self, dev_batch, refs, versions):
         """Model 1: per-record evaluation — state refreshed per record."""

@@ -8,7 +8,7 @@ shapes).  These are the building blocks of the paper's UDF workload:
                     to the one-hot x matmul MXU kernel, kernels/segment_reduce)
   order-by/top-k -> ``segment_topk``: one composite-key sort, no S x R blowup
   spatial join   -> ``radius_count`` / ``radius_topk``: tiled pairwise
-                    distances via the MXU identity |a-b|^2 = |a|^2+|b|^2-2ab
+                    distances, dx*dx + dy*dy elementwise in float32
                     (kernels/spatial_join is the Pallas version)
   contains()     -> ``contains_any``: hashed-token membership (DESIGN.md §2)
 
@@ -178,14 +178,18 @@ def country_keyword_match(text_tokens: Array, country: Array,
 
 
 def pairwise_dist2(points: Array, refs: Array) -> Array:
-    """Squared euclidean distance matrix via the MXU-friendly identity.
+    """Squared euclidean distance matrix, elementwise in float32:
+    ``dx * dx + dy * dy`` on each (point, ref) pair, the Pallas kernel's
+    own arithmetic (kernels/spatial_join).  The MXU identity
+    |p|^2 + |r|^2 - 2 p.r is not used: at |p|^2 ~ 36,000 it cancels away
+    the low bits that decide a pair at the radius, and on a TPU the
+    default matmul precision rounds p.r to bfloat16.
     points: (B, 2); refs: (R, 2) -> (B, R) float32."""
     p = points.astype(jnp.float32)
     r = refs.astype(jnp.float32)
-    d2 = (jnp.sum(p * p, axis=1)[:, None]
-          + jnp.sum(r * r, axis=1)[None, :]
-          - 2.0 * p @ r.T)
-    return jnp.maximum(d2, 0.0)
+    dx = p[:, 0:1] - r[None, :, 0]
+    dy = p[:, 1:2] - r[None, :, 1]
+    return dx * dx + dy * dy
 
 
 def _chunk_map(fn, points: Array, chunk: int):
@@ -268,7 +272,10 @@ def group_count_within_radius(points: Array, refs: Array, group: Array,
                               chunk: int = _SPATIAL_CHUNK) -> Array:
     """Per probe point: counts of in-radius reference points per group
     (Q5/Q6's 'facilities by type').  Returns (B, num_groups) int32.
-    The hit x one-hot contraction is a dense GEMM — MXU-native."""
+    The hit x one-hot contraction is a dense GEMM — MXU-native — and
+    exact at any matmul precision: both operands are 0 or 1, which
+    bfloat16 holds exactly, and each count is at most R < 2**24, which
+    the float32 accumulator holds exactly."""
     r2 = jnp.float32(radius) ** 2
     onehot = jax.nn.one_hot(group, num_groups, dtype=jnp.float32)
     if ref_valid is not None:

@@ -688,6 +688,7 @@ class FeedHandle:
             "computing_records": comp.records,
             "computing_state_builds": comp.state_builds,
             "computing_state_reuses": comp.state_reuses,
+            "computing_upload_bytes": comp.upload_bytes,
             "computing_calibrations": comp.calibrations})
         reg.set_gauges({
             "computing_parse_s": comp.parse_s,

@@ -155,13 +155,16 @@ def test_point_in_rect_chunked_equals_unchunked():
     np.testing.assert_array_equal(np.asarray(a_found), np.asarray(b_found))
 
 
-def test_pairwise_dist2_identity():
+def test_pairwise_dist2_elementwise():
     rng = np.random.default_rng(1)
     a = rng.uniform(-3, 3, (17, 2)).astype(np.float32)
     b = rng.uniform(-3, 3, (23, 2)).astype(np.float32)
     got = np.asarray(ops.pairwise_dist2(jnp.asarray(a), jnp.asarray(b)))
-    want = ((a[:, None, :] - b[None, :, :]) ** 2).sum(-1)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    dx = a[:, None, 0] - b[None, :, 0]
+    dy = a[:, None, 1] - b[None, :, 1]
+    want = dx * dx + dy * dy                 # float32, every step
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,7 @@
 """feedscope tests: journey reconstruction + critical-path attribution
 (core/obs/profile.py), the SLO health model (core/obs/health.py), the
-live ops endpoint (core/obs/server.py), the per-stage calibration split,
-and the empty-histogram nan pins.
+live ops endpoint (core/obs/server.py), the fused chain's even stage
+split, and the empty-histogram nan pins.
 
 Deliberately hypothesis-free: CI runs this module in the minimal
 plan-api container, so the feedscope surface is pinned even where the
@@ -306,45 +306,25 @@ def test_obs_server_smoke_and_health_flip_on_induced_stall():
 
 
 # ---------------------------------------------------------------------------
-# per-stage calibration: measured fractions replace the even split
+# fused chains: one dispatch a batch, apply wall split evenly
 # ---------------------------------------------------------------------------
 
-def test_calibration_weights_attribution_for_fused_chains():
-    store = RefStore()
-    Q.make_reference_tables(store, scale=0.002, seed=7)
-    udf = Q.chain("q1_then_q2", Q.Q1, Q.Q2)
-    runner = ComputingRunner(ComputingSpec(udf, batch_size=50),
-                             store, None)
-    runner.CALIBRATE_EVERY = 1               # instance override: every batch
-    frames = list(SyntheticTweets(seed=4).batches(150, 50))
-    for f in frames:
-        runner.run(f)
-    st = runner.stats
-    assert st.calibrations >= 1
-    weights = runner._stage_weights
-    assert weights is not None
-    assert set(weights) == {u.name for u in udf.stages}
-    assert sum(weights.values()) == pytest.approx(1.0)
-    assert all(w > 0.0 for w in weights.values())
-    # the measured split still conserves the batch walls: per-stage
-    # apply_s sums to the chain's total apply_s
-    per_stage_total = sum(ss.apply_s for ss in st.per_stage.values())
-    assert per_stage_total == pytest.approx(st.apply_s, rel=1e-6)
-    # calibration walls price the attribution, not the feed: apply_s
-    # stays the fused dispatch wall only (invocations unchanged)
-    assert st.invocations == len(frames)
-
-
-def test_even_split_until_first_calibration():
+def test_fused_chain_even_split_and_no_compile_after_first_batch():
     store = RefStore()
     Q.make_reference_tables(store, scale=0.002, seed=7)
     udf = Q.chain("q1q2_even", Q.Q1, Q.Q2)
     runner = ComputingRunner(ComputingSpec(udf, batch_size=50),
                              store, None)
-    assert runner.CALIBRATE_EVERY > 3        # default: no calibration yet
-    for f in SyntheticTweets(seed=2).batches(150, 50):
+    frames = list(SyntheticTweets(seed=2).batches(70 * 50, 50))
+    assert len(frames) == 70
+    runner.run(frames[0])
+    compiled = runner.cache.compiles
+    for f in frames[1:]:
         runner.run(f)
+    # a long fused feed compiles nothing after its first batch
+    assert runner.cache.compiles == compiled
     st = runner.stats
+    assert st.invocations == 70
     assert st.calibrations == 0
     a, b = (st.per_stage[u.name].apply_s for u in udf.stages)
     assert a == pytest.approx(b)             # even split

@@ -80,6 +80,22 @@ def test_segment_topk_compiles_at_envelope_corner(one_chip):
              ((1 << 18,), jnp.int32), ((1 << 18,), jnp.int32))
 
 
+def test_group_count_within_radius_compiles_at_q5_width(one_chip):
+    """Q5's 16-type radius count runs as jnp tiles on every path: the
+    elementwise distances over (512, 50,176) tiles compile for the chip
+    and fuse, leaving no tile in device memory."""
+    from repro.core.enrich import ops
+    facilities = 50_176                    # 50K, trimmed to 256s
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in (((6720, 2), jnp.float32),
+                                  ((facilities, 2), jnp.float32),
+                                  ((facilities,), jnp.int32),
+                                  ((facilities,), jnp.bool_))]
+    compiled = jax.jit(lambda p, r, g, v: ops.group_count_within_radius(
+        p, r, g, 16, 3.0, v)).lower(*specs).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 * facilities
+
+
 def _kernel_instructions(text):
     """The instructions of compiled HLO text that the benchmark's
     ``KERNELS`` patterns match, by kernel."""
