@@ -17,7 +17,8 @@ from repro.kernels.hash_probe.kernel import sorted_probe_pallas
 from repro.kernels.segment_reduce import ref as sr_ref
 from repro.kernels.segment_reduce.kernel import segment_sum_pallas
 from repro.kernels.spatial_join import ref as sj_ref
-from repro.kernels.spatial_join.kernel import radius_join_pallas
+from repro.kernels.spatial_join.kernel import (band_plan, latitude_order,
+                                               radius_join_pallas)
 
 
 # ---------------------------------------------------------------------------
@@ -107,23 +108,163 @@ def test_sorted_probe_kernel_property(b, r, seed):
 # spatial_join
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("b,r,k", [(40, 60, 3), (300, 2000, 8), (1, 1, 2),
-                                   (257, 1025, 1)])
-def test_radius_join_kernel(b, r, k):
+def _radius_case(layout, b, r, k):
+    """(px, py, rx, ry, valid, radius, block_b, block_r) for one layout of
+    probe and reference points."""
+    f32 = np.float32
     rng = np.random.default_rng(b + r + k)
-    px = jnp.asarray(rng.uniform(-10, 10, b).astype(np.float32))
-    py = jnp.asarray(rng.uniform(-10, 10, b).astype(np.float32))
-    rx = jnp.asarray(rng.uniform(-10, 10, r).astype(np.float32))
-    ry = jnp.asarray(rng.uniform(-10, 10, r).astype(np.float32))
-    valid = jnp.asarray((rng.random(r) < 0.9))
-    gi, gd, gc = radius_join_pallas(px, py, rx, ry, 4.0, k, valid,
-                                    block_b=128, block_r=256,
-                                    interpret=True)
-    wi, wd, wc = sj_ref.radius_join(px, py, rx, ry, 4.0, k, valid)
+    if layout == "uniform":
+        px, py, rx, ry = (rng.uniform(-10, 10, n).astype(f32)
+                          for n in (b, b, r, r))
+        return px, py, rx, ry, rng.random(r) < 0.9, 4.0, 128, 256
+    if layout == "spread":          # many latitude blocks: the band prunes
+        px, rx = (rng.uniform(-60, 60, n).astype(f32) for n in (b, r))
+        py, ry = (rng.uniform(-20, 20, n).astype(f32) for n in (b, r))
+        return px, py, rx, ry, None, 1.5, 64, 256
+    if layout == "one_lat":         # one band: the plan is the whole grid
+        px, rx = np.full(b, 5.0, f32), np.full(r, 5.0, f32)
+        py, ry = (rng.uniform(-10, 10, n).astype(f32) for n in (b, r))
+        return px, py, rx, ry, None, 2.0, 64, 128
+    if layout == "dup_refs":        # equal points in different blocks
+        base = rng.integers(-40, 41, (r // 4, 2)).astype(f32) / 4
+        pts = np.tile(base, (4, 1))[rng.permutation(r)]
+        probe = rng.integers(-40, 41, (b, 2)).astype(f32) / 4
+        return (probe[:, 0], probe[:, 1], pts[:, 0], pts[:, 1], None, 3.0,
+                64, 128)
+    if layout == "edge":            # exactly the radius away, and one ulp
+        radius = 1.5
+        px = np.zeros(b, f32)
+        py = np.arange(b, dtype=f32)
+        beyond = np.nextafter(f32(radius), f32(np.inf))
+        lat = np.concatenate([np.full(128, radius, f32),
+                              np.full(128, beyond, f32),
+                              np.full(128, -radius, f32),
+                              np.full(128, -beyond, f32),
+                              rng.uniform(5, 60, r - 512).astype(f32)])
+        order = rng.permutation(r)
+        rx = lat[order]
+        ry = np.tile(np.arange(128, dtype=f32), r // 128 + 1)[:r][order]
+        return px, py, rx, ry, None, radius, 64, 128
+    if layout == "invalid":         # whole blocks and scattered rows off
+        px, py, rx, ry = (rng.uniform(-10, 10, n).astype(f32)
+                          for n in (b, b, r, r))
+        valid = (rng.random(r) < 0.5) & (np.arange(r) // 128 % 3 != 1)
+        return px, py, rx, ry, valid, 3.0, 64, 128
+    if layout == "few_hits":        # k above every probe's count
+        px, py, rx, ry = (rng.uniform(-30, 30, n).astype(f32)
+                          for n in (b, b, r, r))
+        return px, py, rx, ry, None, 1.0, 64, 128
+    raise KeyError(layout)
+
+
+@pytest.mark.parametrize("b,r,k,layout", [
+    pytest.param(40, 60, 3, "uniform", id="40-60-3"),
+    pytest.param(300, 2000, 8, "uniform", id="300-2000-8"),
+    pytest.param(1, 1, 2, "uniform", id="1-1-2"),
+    pytest.param(257, 1025, 1, "uniform", id="257-1025-1"),
+    pytest.param(600, 3000, 4, "spread", id="spread-600-3000-4"),
+    pytest.param(200, 700, 5, "one_lat", id="one_lat-200-700-5"),
+    pytest.param(150, 800, 6, "dup_refs", id="dup_refs-150-800-6"),
+    pytest.param(100, 1000, 8, "edge", id="edge-100-1000-8"),
+    pytest.param(300, 1500, 4, "invalid", id="invalid-300-1500-4"),
+    pytest.param(200, 500, 8, "few_hits", id="few_hits-200-500-8"),
+])
+def test_radius_join_kernel(b, r, k, layout):
+    px, py, rx, ry, valid, radius, bb, br = (
+        jnp.asarray(v) if isinstance(v, np.ndarray) else v
+        for v in _radius_case(layout, b, r, k))
+    gi, gd, gc = radius_join_pallas(px, py, rx, ry, radius, k, valid,
+                                    block_b=bb, block_r=br, interpret=True)
+    wi, wd, wc = sj_ref.radius_join(px, py, rx, ry, radius, k, valid)
     np.testing.assert_array_equal(np.asarray(gc), np.asarray(wc))
     np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
     np.testing.assert_allclose(np.asarray(gd), np.asarray(wd),
                                rtol=1e-5, atol=1e-5)
+    if layout == "edge":            # the radius counts, one ulp past not
+        np.testing.assert_array_equal(np.asarray(gc)[:64], 2)
+    if layout == "few_hits":
+        assert np.asarray(gc).max() < k
+
+
+def _q4_band_inputs():
+    """The q1q7_backlog cell's Q4 call, in the kernel's order: 8,192
+    probes (6,720 tweets and 1,472 padding rows at (0, 0)) against 50,176
+    monument rows (50,000 drawn as the benchmark draws them, the rest
+    invalid), seed 0."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import data
+    px = np.zeros(8192, np.float32)
+    px[:6720] = data.parsed_columns(data.tweets(0, 0, 6720))["lat"]
+    rx = np.zeros(50_176, np.float32)
+    rx[:50_000] = data.reference_tables({"monuments": 50_000}, 0
+                                        )["monuments"]["lat"]
+    valid = jnp.asarray(np.arange(rx.shape[0]) < 50_000)
+    order = latitude_order(jnp.asarray(px), jnp.ones(px.shape, bool))
+    rorder = latitude_order(jnp.asarray(rx), valid)
+    return jnp.asarray(px)[order], jnp.asarray(rx)[rorder], valid[rorder]
+
+
+def _visited(lo, hi):
+    return int(np.sum(np.asarray(hi) - np.asarray(lo)))
+
+
+def test_band_plan_visits_few_tiles_of_the_q4_call():
+    px, rx, valid = _q4_band_inputs()
+    lo, hi = band_plan(px, rx, valid, 1.5, 256, 1024)
+    assert _visited(lo, hi) <= 0.10 * 32 * 49
+    assert np.all(np.asarray(hi) > np.asarray(lo))
+
+
+def test_band_plan_visits_every_tile_at_one_latitude():
+    px, rx = jnp.full((512,), 7.0, jnp.float32), jnp.full((2048,), 7.0,
+                                                         jnp.float32)
+    lo, hi = band_plan(px, rx, jnp.ones((2048,), bool), 1.5, 64, 256)
+    np.testing.assert_array_equal(np.asarray(lo), 0)
+    np.testing.assert_array_equal(np.asarray(hi), 2048 // 256)
+
+
+def test_band_plan_visits_a_block_exactly_the_radius_away():
+    """Probes at latitude 0; reference blocks at -30, exactly 1.5, one
+    float32 ulp past 1.5, and 40: float32 d2 is exactly 1.5² in the
+    second, which must be met, and above it in the third, which need not."""
+    radius = 1.5
+    beyond = np.nextafter(np.float32(radius), np.float32(np.inf))
+    assert np.float32(beyond) * np.float32(beyond) > np.float32(radius ** 2)
+    rx = np.concatenate([np.full(128, v, np.float32)
+                         for v in (-30.0, radius, beyond, 40.0)])
+    lo, hi = band_plan(jnp.zeros((8,), jnp.float32), jnp.asarray(rx),
+                       jnp.ones((512,), bool), radius, 8, 128)
+    assert (int(lo[0]), int(hi[0])) == (1, 2)
+
+
+def test_band_plan_skips_blocks_of_invalid_references():
+    rx = jnp.zeros((256,), jnp.float32)
+    valid = jnp.arange(256) < 128
+    lo, hi = band_plan(jnp.zeros((8,), jnp.float32), rx, valid, 1.0, 8, 128)
+    assert (int(lo[0]), int(hi[0])) == (0, 1)
+
+
+def test_latitude_order_sorts_kept_points_and_puts_the_rest_last():
+    """Kept points ascend to within one of the 2**18 steps that 5,000
+    points leave room for; the rest, NaN included, follow."""
+    rng = np.random.default_rng(3)
+    x = rng.uniform(-60, 60, 5000).astype(np.float32)
+    x[:20] = x[20:40]
+    x[50] = np.nan
+    keep = rng.random(5000) < 0.8
+    keep[50] = True
+    perm = np.asarray(latitude_order(jnp.asarray(x), jnp.asarray(keep)))
+    assert sorted(perm.tolist()) == list(range(5000))
+    n_kept = int(keep.sum()) - 1
+    kept = perm[:n_kept]
+    assert np.all(keep[kept])
+    step = (x[kept].max() - x[kept].min()) / (2**18 - 2)
+    assert np.all(np.diff(x[kept]) >= -step)
+    assert set(perm[n_kept:].tolist()) == set(np.flatnonzero(~keep)) | {50}
 
 
 @settings(max_examples=10, deadline=None)

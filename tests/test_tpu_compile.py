@@ -51,8 +51,9 @@ def _compile(fn, one_chip, *args):
     assert jax.config.jax_enable_x64
     specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
              for shape, dtype in args]
-    compiled = jax.jit(fn).lower(*specs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 def test_hash_probe_compiles_at_suspicious_names_width(one_chip):
@@ -60,13 +61,24 @@ def test_hash_probe_compiles_at_suspicious_names_width(one_chip):
              ((PROBE_ROWS,), jnp.int64), ((SUSPICIOUS_NAMES,), jnp.int64))
 
 
-@pytest.mark.parametrize("refs,k", [(MONUMENTS, 8), (10_240, 3)])
+@pytest.mark.parametrize("refs,k", [(MONUMENTS, 8), (10_240, 3),
+                                    (50_176, 8)])
 def test_spatial_join_compiles_at_paper_widths(one_chip, refs, k):
+    """Q4's call (8 nearest within 1.5 degrees, monuments trimmed to
+    50,176 rows in ``q1q7_backlog``) and Q5's and Q7's (3 within 3
+    degrees): the latitude band's two run bounds are scalar-prefetched
+    ahead of the probe and reference blocks."""
     f32 = jnp.float32
-    _compile(lambda px, py, rx, ry, valid: radius_join_pallas(
-                 px, py, rx, ry, 1.5, k, valid),
-             one_chip, ((PROBE_ROWS,), f32), ((PROBE_ROWS,), f32),
-             ((refs,), f32), ((refs,), f32), ((refs,), jnp.bool_))
+    radius = 1.5 if k == 8 else 3.0
+    text = _compile(lambda px, py, rx, ry, valid: radius_join_pallas(
+                        px, py, rx, ry, radius, k, valid),
+                    one_chip, ((PROBE_ROWS,), f32), ((PROBE_ROWS,), f32),
+                    ((refs,), f32), ((refs,), f32), ((refs,), jnp.bool_))
+    blocks = PROBE_ROWS // 256
+    call = next(line for line in text.splitlines()
+                if "%radius_join_pallas" in line and "tpu_custom_call" in line)
+    assert f"operand_layout_constraints={{s32[{blocks}]{{0}}, " \
+           f"s32[{blocks}]{{0}}, f32[{PROBE_ROWS},1]" in call
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
